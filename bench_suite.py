@@ -2378,27 +2378,30 @@ def run_suite():
     wanted = [a for a in args if not a.startswith("--")] or list(CONFIGS)
     if "mesh-shard" in wanted and "xla_force_host_platform_device_count" \
             not in os.environ.get("XLA_FLAGS", ""):
-        # the mesh-shard config needs a multi-device backend; on a
-        # single-chip/CPU box, force an 8-device virtual host mesh
-        # BEFORE jax initializes (same as tests/conftest.py).  The
-        # flag only affects the CPU platform — harmless on real TPU.
+        # the mesh-shard config needs a multi-device backend; a CPU
+        # rehearsal gets an 8-device virtual host mesh BEFORE jax
+        # initializes (same as tests/conftest.py).  The flag only
+        # affects the CPU platform — harmless on a TPU.
         os.environ["XLA_FLAGS"] = (
             os.environ.get("XLA_FLAGS", "") +
             " --xla_force_host_platform_device_count=8").strip()
-    from cilium_tpu.utils.platform import apply_env_platform
-    _backend, on_accel = apply_env_platform()
+    from cilium_tpu.utils.platform import (emit_result,
+                                           enable_compile_cache,
+                                           require_device)
+    platform, kind, count = require_device()
+    enable_compile_cache()
+    on_accel = platform == "tpu"
+    device = {"platform": platform, "kind": kind, "count": count}
     for name in wanted:
         if name in ("capacity", "mesh-shard"):
             r = CONFIGS[name](on_accel, full_capacity=full_capacity)
         else:
             r = CONFIGS[name](on_accel)
-        print(json.dumps(r))
-
-
-def main():
-    from cilium_tpu.utils.platform import main_with_fallback
-    main_with_fallback(run_suite, timeout=900, fail_metric="suite_failed")
+        r.setdefault("extra", {}).update(backend=platform,
+                                         on_accel=on_accel,
+                                         device=device)
+        emit_result(r)
 
 
 if __name__ == "__main__":
-    main()
+    run_suite()

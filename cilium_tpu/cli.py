@@ -884,7 +884,9 @@ def cmd_agent(args) -> int:
     from .daemon.rest import APIServer
     from .kvstore.backend import setup_client
     from .utils.option import DaemonConfig
+    from .utils.platform import enable_compile_cache
 
+    enable_compile_cache()
     cfg = DaemonConfig(cluster_name=args.cluster_name,
                        cluster_id=args.cluster_id,
                        state_dir=args.state_dir,

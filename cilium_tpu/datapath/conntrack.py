@@ -87,7 +87,7 @@ class CTPack(NamedTuple):
     """The packed CT representation: THREE stacked int32 buffers —
     three jitted-step leaves instead of eight, donated as a unit.
 
-    The split follows XLA's copy-insertion boundaries, not taxonomy:
+    The split follows XLA's copy-insertion boundaries, not field kind:
 
     - ``keys`` [4, N+1] (k0..k3) has a strictly linear read -> write ->
       read -> write chain through the create rounds, so its buffer

@@ -1168,22 +1168,26 @@ class Datapath:
 
     def _lower_args_packed(self, packed, now: int = 1):
         """The exact argument tuple ``_step_packed`` dispatches —
-        the jit-lowering/introspection surface for tests.  An
-        L7-enabled engine's step takes the payload lane too (absent
-        matrix stands in, as for payload-less dispatch)."""
+        the jit-lowering/introspection surface for tests.  A flows-on
+        engine's step takes the flow table, an L7-enabled one the
+        payload lane too (absent matrix stands in, as for payload-less
+        dispatch)."""
         args = (self._tbufs4, self.ct.state, self._counters, packed,
                 jnp.int32(now))
+        flows = None if self.flows is None else self.flows.state
         pl = None
         if self._l7_fast is not None:
             pl = jnp.asarray(
                 self._payload_in(None, int(packed.shape[1])))
         if self._analytics_on:
-            return args + (None, pl, self.threat_state,
+            return args + (flows, pl, self.threat_state,
                            self.analytics_state)
         if self._threat is not None:
-            return args + (None, pl, self.threat_state)
+            return args + (flows, pl, self.threat_state)
         if pl is not None:
-            return args + (None, pl)
+            return args + (flows, pl)
+        if flows is not None:
+            return args + (flows,)
         return args
 
     # -- the hot path --------------------------------------------------------

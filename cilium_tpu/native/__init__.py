@@ -1,8 +1,8 @@
 """Native host runtime: ctypes bindings over runtime.cc.
 
 Build-on-demand: the shared library compiles once with g++ into
-``cilium_tpu/native/_build/`` (keyed by source hash) and loads via
-ctypes — no pybind11, no pip. Exposes:
+``cilium_tpu/native/_build/`` (keyed by source and command hash) and
+loads via ctypes — no pybind11, no pip. Exposes:
 
 - ``PacketRing``: lock-free SPSC packet-header ring whose drain fills
   struct-of-arrays numpy buffers (zero-copy handoff to the batched TPU
@@ -41,16 +41,24 @@ _lib = None
 _lib_lock = threading.Lock()
 
 
+# the g++ invocation minus its output path; part of the build's key
+_CXX = ("g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-pthread")
+
+
 def _build() -> str:
+    """Build the tracked ``runtime.cc`` into ``_build/``.  The file
+    name hashes the source AND the compiler command, so a library left
+    there by another source or another command is never loaded."""
+    h = hashlib.sha256()
     with open(_SRC, "rb") as f:
-        digest = hashlib.sha256(f.read()).hexdigest()[:16]
-    so_path = os.path.join(_BUILD_DIR, f"runtime-{digest}.so")
+        h.update(f.read())
+    h.update("\0".join(_CXX).encode())
+    so_path = os.path.join(_BUILD_DIR, f"runtime-{h.hexdigest()[:16]}.so")
     if os.path.exists(so_path):
         return so_path
     os.makedirs(_BUILD_DIR, exist_ok=True)
     tmp = so_path + f".tmp{os.getpid()}"
-    cmd = ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-pthread",
-           "-o", tmp, _SRC]
+    cmd = [*_CXX, "-o", tmp, _SRC]
     proc = subprocess.run(cmd, capture_output=True, text=True)
     if proc.returncode != 0:
         raise RuntimeError(

@@ -27,7 +27,7 @@ dense wins on small-to-mid rule sets where gathers dominate.
 from __future__ import annotations
 
 import functools
-from typing import List, NamedTuple, Optional, Sequence, Tuple
+from typing import List, NamedTuple, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -36,12 +36,7 @@ import numpy as np
 from ..compiler.policy_tables import pack_key, pack_meta
 from ..policy.mapstate import PolicyMapState
 
-try:
-    from jax.experimental import pallas as pl
-    HAS_PALLAS = True
-except ImportError:  # pragma: no cover
-    pl = None
-    HAS_PALLAS = False
+from jax.experimental import pallas as pl
 
 VERDICT_DROP = -1
 
@@ -214,17 +209,14 @@ def _dense_tiled_kernel(ep_ref, ka_ref, kb_ref, val_ref, pep_ref, pid_ref,
 def dense_verdict_pallas(tables: DenseTables, pkt_ep, pkt_ident,
                          pkt_dport, pkt_proto, pkt_dir, pkt_len,
                          block_b: int = 256, tile_n: int = TILE_N,
-                         interpret: Optional[bool] = None):
+                         interpret: bool = False):
     """Pallas dense engine, entry axis tiled through VMEM.
 
     Returns (verdict [B], counter deltas (packets [N], bytes [N])).
     No entry-count cap: the grid walks ceil(N / tile_n) tiles per
-    packet block.  Requires B % block_b == 0.
+    packet block.  Requires B % block_b == 0.  ``interpret`` runs the
+    kernel in the Pallas interpreter (CPU tests only).
     """
-    if not HAS_PALLAS:
-        raise RuntimeError("pallas unavailable")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     n = tables.ep.shape[0]
     b = pkt_ep.shape[0]
     block_b = min(block_b, b)
@@ -353,18 +345,19 @@ class DenseVerdictEngine:
     """Host wrapper: compile states, run batches, keep counters."""
 
     def __init__(self, map_states: Sequence[PolicyMapState],
-                 use_pallas: bool = False, block_b: int = 256):
+                 use_pallas: bool = False, block_b: int = 256,
+                 interpret: bool = False):
         self.tables = compile_dense(map_states)
         n = self.tables.ep.shape[0]
         # the tiled kernel has no entry cap (entry axis walks VMEM in
         # TILE_N tiles), so pallas is available at any N
-        self.use_pallas = use_pallas and HAS_PALLAS
+        self.use_pallas = use_pallas
         self.block_b = block_b
         self.counters_packets = jnp.zeros(n, jnp.uint32)
         self.counters_bytes = jnp.zeros(n, jnp.uint32)
         self._jit_step = jax.jit(dense_verdict_step, donate_argnums=(1, 2))
         self._jit_pallas = jax.jit(functools.partial(
-            dense_verdict_pallas, block_b=block_b))
+            dense_verdict_pallas, block_b=block_b, interpret=interpret))
 
     def __call__(self, pkt_ep, pkt_ident, pkt_dport, pkt_proto, pkt_dir,
                  pkt_len):

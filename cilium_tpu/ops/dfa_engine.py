@@ -189,10 +189,7 @@ class DFAEngine:
         self.batch_hint = int(batch_hint)
         s = int(compiled.num_states)
         if on_accel is None:
-            try:
-                on_accel = jax.default_backend() != "cpu"
-            except Exception:  # noqa: BLE001 — backend probe best-effort
-                on_accel = False
+            on_accel = jax.default_backend() != "cpu"
         self.on_accel = bool(on_accel)
         # quantize for VMEM residency on accelerators; int32 on CPU
         # (narrow gathers measure slower there and cache still fits)
