@@ -1,0 +1,6 @@
+"""step_device_us.rr: see PERF.md §3."""
+from readers import step_device_us
+
+
+def read(ctx):
+    return step_device_us(ctx)
