@@ -25,7 +25,7 @@ from ..observability.jitstats import jit_telemetry
 from ..observability.pressure import compute_pressure
 from ..observability.stages import record_stage
 from ..policy.mapstate import PolicyMapState
-from ..utils.metrics import POLICY_VERDICTS
+from ..utils.metrics import count_policy_verdicts
 from .conntrack import ConntrackTable, ct_host_fields
 from .lb import (CompiledLB, CompiledLB6, LoadBalancer, Service,
                  Service6, compile_lb, compile_lb6)
@@ -122,10 +122,10 @@ class Datapath:
         # into this device table inside the same compiled program
         self.flows = None
         # runtime self-telemetry (observability/): stage slices,
-        # jit-cache accounting, verdict-outcome counters, and the
-        # revision-served hook the policy-propagation tracker uses to
-        # close the import->first-verdict loop.  One flag gates all of
-        # it so the bench can prove the disabled path costs ~0.
+        # verdict-outcome counters, and the revision-served hook the
+        # policy-propagation tracker uses to close the import->first-
+        # verdict loop.  One flag gates all of it so the bench can
+        # prove the disabled path costs ~0.
         self.telemetry_enabled = True
         self.on_revision_served = None  # callable(revision)
         self._served_revision = 0
@@ -137,6 +137,8 @@ class Datapath:
         # per-second device timestamp cache: steady-state dispatch
         # reuses the same jnp scalar instead of a fresh H2D per batch
         self._ts_cache: Optional[Tuple[int, object]] = None
+        # compiles are counted from JAX's own compile events
+        jit_telemetry.attach()
         # the shared continuous micro-batching dispatcher
         # (datapath/serving.py), created on first use
         self._serving = None
@@ -1065,6 +1067,8 @@ class Datapath:
                 return step_fn(tables, ct, counters, batch, now,
                                flows, payload, threat, analytics,
                                **statics)
+            # the program (and its compile events) carry the step's name
+            g.__name__ = g.__qualname__ = step_fn.__name__
             return jax.jit(g, donate_argnums=(1, 2))
 
         from ..parallel import packing
@@ -1304,9 +1308,7 @@ class Datapath:
                                                   outs[tail + 1])
             served = self._revision_newly_served_locked()
         if telem:
-            self._account_dispatch("engine-v4", "datapath.process",
-                                   step, pkt.endpoint.shape[0],
-                                   t0, t_lock, verdict)
+            self._account_dispatch("engine-v4", t0, t_lock, verdict)
         if served:
             self._notify_revision_served(served)
         return verdict, event, identity, nat
@@ -1354,9 +1356,7 @@ class Datapath:
                                                   outs[tail + 1])
             served = self._revision_newly_served_locked()
         if telem:
-            self._account_dispatch("engine-v6", "datapath.process6",
-                                   step, pkt.endpoint.shape[0],
-                                   t0, t_lock, verdict)
+            self._account_dispatch("engine-v6", t0, t_lock, verdict)
         if served:
             self._notify_revision_served(served)
         return verdict, event, identity, nat
@@ -1368,7 +1368,9 @@ class Datapath:
         entry: a single H2D transfer per batch instead of ten, with
         the per-field unpack fused into the compiled program.  Same
         verdict/event/identity/nat outputs, same async-dispatch and
-        narrow-lock contract as process().
+        narrow-lock contract as process().  ``policy_verdicts_total``
+        is left to the caller, which copies the verdicts to the host
+        anyway (the serving lane counts them there).
 
         ``payload`` is the optional [B, W] L7 payload lane riding
         beside the field matrix (its own H2D) when the fast-verdict
@@ -1417,9 +1419,7 @@ class Datapath:
                                                   outs[tail + 1])
             served = self._revision_newly_served_locked()
         if telem:
-            self._account_dispatch("engine-v4", "datapath.process",
-                                   step, int(packed.shape[1]),
-                                   t0, t_lock, verdict)
+            self._account_dispatch("engine-v4", t0, t_lock)
         if served:
             self._notify_revision_served(served)
         return verdict, event, identity, nat
@@ -1501,20 +1501,17 @@ class Datapath:
 
     # -- self-telemetry (observability/) -------------------------------------
 
-    def _account_dispatch(self, family: str, entry: str, step,
-                          batch: int, t0: float, t_lock: float,
-                          verdict) -> None:
-        """Stage slices + jit-cache classification + deferred
-        verdict-outcome accounting for one dispatch.  Runs AFTER the
-        engine lock is released — accounting (and the occasional
-        force-flush device read) must never extend the lock hold."""
-        t_done = time.perf_counter()
+    def _account_dispatch(self, family: str, t0: float, t_lock: float,
+                          verdict=None) -> None:
+        """Stage slices + deferred verdict-outcome accounting for one
+        dispatch (``verdict`` None: the caller counts the outcomes).
+        Runs AFTER the engine lock is released — accounting (and the
+        occasional force-flush device read) must never extend the lock
+        hold."""
         record_stage(family, "lock-wait", t_lock - t0)
-        record_stage(family, "dispatch", t_done - t_lock)
-        # a first call per (program, batch geometry) paid tracing +
-        # XLA compile synchronously inside the dispatch slice
-        jit_telemetry.record(entry, id(step), int(batch),
-                             t_done - t_lock)
+        record_stage(family, "dispatch", time.perf_counter() - t_lock)
+        if verdict is None:
+            return
         with self._verdict_lock:
             self._pending_verdicts.append(verdict)
             self._flush_verdict_counts(
@@ -1542,18 +1539,7 @@ class Datapath:
                 v = np.asarray(arr)  # sync-ok: is_ready-gated (or a bounded force-flush outside the device lock)
             except Exception:  # noqa: BLE001 — deleted buffer
                 continue
-            denied = int((v < 0).sum())
-            redirected = int((v > 0).sum())
-            allowed = v.shape[0] - denied - redirected
-            if allowed:
-                POLICY_VERDICTS.inc(allowed,
-                                    labels={"outcome": "allowed"})
-            if denied:
-                POLICY_VERDICTS.inc(denied,
-                                    labels={"outcome": "denied"})
-            if redirected:
-                POLICY_VERDICTS.inc(redirected,
-                                    labels={"outcome": "redirected"})
+            count_policy_verdicts(v)
         self._pending_verdicts = remaining
 
     def flush_telemetry(self) -> None:

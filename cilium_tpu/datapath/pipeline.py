@@ -433,176 +433,191 @@ def full_datapath_step(tables: FullTables, ct, counters: Counters,
                           VERDICT_DROP_L7, VERDICT_DROP_THREAT)
 
     # 1. Prefilter (bpf_xdp.c:158 check_filters).
-    if tables.pf_key_a.shape[0] > 0:
-        pf_hit, _ = lpm_lookup(tables.pf_masks, tables.pf_key_a,
-                               tables.pf_key_b, tables.pf_value,
-                               tables.pf_plens, pkt.saddr, pf_probe)
-    else:
-        pf_hit = jnp.zeros(pkt.saddr.shape[0], bool)
+    with jax.named_scope("prefilter"):
+        if tables.pf_key_a.shape[0] > 0:
+            pf_hit, _ = lpm_lookup(tables.pf_masks, tables.pf_key_a,
+                                   tables.pf_key_b, tables.pf_value,
+                                   tables.pf_plens, pkt.saddr, pf_probe)
+        else:
+            pf_hit = jnp.zeros(pkt.saddr.shape[0], bool)
 
     # 2. Service LB DNAT (lb.h lb4_local).
-    daddr, dport, rev_nat, is_svc = lb_step(
-        tables.lb, pkt.daddr, pkt.dport, pkt.proto, pkt.saddr, pkt.sport,
-        max_probe=lb_probe)
+    with jax.named_scope("lb"):
+        daddr, dport, rev_nat, is_svc = lb_step(
+            tables.lb, pkt.daddr, pkt.dport, pkt.proto, pkt.saddr, pkt.sport,
+            max_probe=lb_probe)
 
     # 3. Conntrack on the DNAT'd tuple (bpf_lxc.c:501 ct_lookup4) — the
     # create decision comes after the policy verdict.
-    ctb = CTBatch(saddr=pkt.saddr, daddr=daddr, sport=pkt.sport,
-                  dport=dport, proto=pkt.proto, direction=pkt.direction,
-                  tcp_flags=pkt.tcp_flags,
-                  related=jnp.zeros_like(pkt.proto))
+    with jax.named_scope("ct"):
+        ctb = CTBatch(saddr=pkt.saddr, daddr=daddr, sport=pkt.sport,
+                      dport=dport, proto=pkt.proto, direction=pkt.direction,
+                      tcp_flags=pkt.tcp_flags,
+                      related=jnp.zeros_like(pkt.proto))
 
     # 4. ipcache: remote identity from the *peer* address (src on
     # ingress, dst on egress — bpf_lxc.c:205/eps.h lookup).
-    peer = jnp.where(pkt.direction == 0, pkt.saddr, daddr)
-    found, ident = lpm_lookup(tables.datapath.lpm_masks,
-                              tables.datapath.lpm_key_a,
-                              tables.datapath.lpm_key_b,
-                              tables.datapath.lpm_value,
-                              tables.datapath.lpm_plens, peer, lpm_probe)
-    identity = jnp.where(found, ident, jnp.int32(WORLD_IDENTITY))
-    # Overlay decap: the sending node stamped the source identity into
-    # the tunnel key; it wins over the local ipcache view
-    # (bpf_overlay.c:151 key.tunnel_id -> ipv4_local_delivery secctx).
-    if pkt.from_overlay is not None:
-        decap = (pkt.from_overlay != 0) & (pkt.direction == 0)
-        identity = jnp.where(decap, pkt.tunnel_id, identity)
-    # Proxy re-entry: the mark carries the original source identity of
-    # a proxied flow (bpf_netdev.c:128-146) — without it the upstream
-    # leg would classify as the proxy host / WORLD.
-    if pkt.mark_identity is not None:
-        identity = jnp.where(pkt.mark_identity > 0,
-                             pkt.mark_identity, identity)
+    with jax.named_scope("ipcache"):
+        peer = jnp.where(pkt.direction == 0, pkt.saddr, daddr)
+        found, ident = lpm_lookup(tables.datapath.lpm_masks,
+                                  tables.datapath.lpm_key_a,
+                                  tables.datapath.lpm_key_b,
+                                  tables.datapath.lpm_value,
+                                  tables.datapath.lpm_plens, peer, lpm_probe)
+        identity = jnp.where(found, ident, jnp.int32(WORLD_IDENTITY))
+        # Overlay decap: the sending node stamped the source identity into
+        # the tunnel key; it wins over the local ipcache view
+        # (bpf_overlay.c:151 key.tunnel_id -> ipv4_local_delivery secctx).
+        if pkt.from_overlay is not None:
+            decap = (pkt.from_overlay != 0) & (pkt.direction == 0)
+            identity = jnp.where(decap, pkt.tunnel_id, identity)
+        # Proxy re-entry: the mark carries the original source identity of
+        # a proxied flow (bpf_netdev.c:128-146) — without it the upstream
+        # leg would classify as the proxy host / WORLD.
+        if pkt.mark_identity is not None:
+            identity = jnp.where(pkt.mark_identity > 0,
+                                 pkt.mark_identity, identity)
 
     # 5. Policy verdict (bpf/lib/policy.h __policy_can_access).
-    vb = PacketBatch(endpoint=pkt.endpoint, identity=identity,
-                     dport=dport, proto=pkt.proto,
-                     direction=pkt.direction, length=pkt.length,
-                     is_fragment=pkt.is_fragment)
-    if with_provenance or with_l7_fast:
-        # the fast-verdict stage needs the matched slot even when
-        # provenance outputs are off (the unused tier is dead code XLA
-        # eliminates; the lookups are shared either way)
-        pol_verdict, counters, pol_slot, pol_tier = verdict_step(
-            tables.datapath.key_id, tables.datapath.key_meta,
-            tables.datapath.value, counters, vb, policy_probe,
-            with_provenance=True)
-    else:
-        pol_verdict, counters = verdict_step(
-            tables.datapath.key_id, tables.datapath.key_meta,
-            tables.datapath.value, counters, vb, policy_probe)
+    with jax.named_scope("policy"):
+        vb = PacketBatch(endpoint=pkt.endpoint, identity=identity,
+                         dport=dport, proto=pkt.proto,
+                         direction=pkt.direction, length=pkt.length,
+                         is_fragment=pkt.is_fragment)
+        if with_provenance or with_l7_fast:
+            # the fast-verdict stage needs the matched slot even when
+            # provenance outputs are off (the unused tier is dead code XLA
+            # eliminates; the lookups are shared either way)
+            pol_verdict, counters, pol_slot, pol_tier = verdict_step(
+                tables.datapath.key_id, tables.datapath.key_meta,
+                tables.datapath.value, counters, vb, policy_probe,
+                with_provenance=True)
+        else:
+            pol_verdict, counters = verdict_step(
+                tables.datapath.key_id, tables.datapath.key_meta,
+                tables.datapath.value, counters, vb, policy_probe)
 
     # 5.5 On-device L7 fast verdict: decide first-bytes-decidable
     # redirects inline from the payload lane — a fast-allowed flow
     # creates its CT entry with proxy port 0 (the whole connection
     # bypasses the proxy), a fast-denied flow creates nothing.
-    if with_l7_fast:
-        pol_verdict, l7_fast_allow, l7_fast_deny = _l7_fast_stage(
-            tables, payload, pol_verdict, pol_slot, k=l7_k, c1=l7_c1)
+    with jax.named_scope("l7"):
+        if with_l7_fast:
+            pol_verdict, l7_fast_allow, l7_fast_deny = _l7_fast_stage(
+                tables, payload, pol_verdict, pol_slot, k=l7_k, c1=l7_c1)
 
     # 6. CT step. Creation is gated on the policy allowing the flow
     # (bpf_lxc.c:545 ct_create4 after policy_can_egress); prefilter-
     # dropped packets may neither create nor touch live entries; new
     # entries record the flow's rev-NAT index and proxy port so the
     # whole connection keeps its NAT and L7 redirect.
-    create_ok = (pol_verdict >= 0) & ~pf_hit
-    proxy_in = jnp.maximum(pol_verdict, 0)
-    ct_verdict, ct_rev_nat, ct_proxy, ct = ct_step(
-        ct, ctb, now, create_ok, update_mask=~pf_hit,
-        rev_nat_in=rev_nat, proxy_port_in=proxy_in,
-        slots=ct_slots, max_probe=ct_probe)
+    with jax.named_scope("ct"):
+        create_ok = (pol_verdict >= 0) & ~pf_hit
+        proxy_in = jnp.maximum(pol_verdict, 0)
+        ct_verdict, ct_rev_nat, ct_proxy, ct = ct_step(
+            ct, ctb, now, create_ok, update_mask=~pf_hit,
+            rev_nat_in=rev_nat, proxy_port_in=proxy_in,
+            slots=ct_slots, max_probe=ct_probe)
 
     # 7. Final verdict: prefilter drop beats everything; established
     # flows follow their CT entry (including its recorded proxy port);
     # CT_NEW flows take the policy verdict.
-    established = ct_verdict != CT_NEW
-    verdict = jnp.where(
-        pf_hit, jnp.int32(VERDICT_DROP),
-        jnp.where(established, ct_proxy, pol_verdict))
+    with jax.named_scope("verdict"):
+        established = ct_verdict != CT_NEW
+        verdict = jnp.where(
+            pf_hit, jnp.int32(VERDICT_DROP),
+            jnp.where(established, ct_proxy, pol_verdict))
 
     # 7.5 Inline threat scoring (threat/stage.py): per-packet anomaly
     # score from the flow-table probe + window aggregates + tuple
     # features; enforce-mode arms override allow/redirect verdicts
     # BEFORE the event/overlay stages so a threat-dropped packet never
     # encaps and a threat-redirect routes to the proxy like any other.
-    if with_threat:
-        from ..threat.stage import threat_stage
-        t_src, t_dst = _flow_identities(tables.ep_identity,
-                                        pkt.endpoint, identity,
-                                        pkt.direction)
-        verdict, threat, threat_out, thr_drop, thr_redir, rl_drop = \
-            threat_stage(
-                tables, threat, flows, verdict,
-                identity=identity, dport=dport, proto=pkt.proto,
-                tcp_flags=pkt.tcp_flags, length=pkt.length,
-                is_fragment=pkt.is_fragment, established=established,
-                saddr_w=pkt.saddr, daddr_w=daddr, sport=pkt.sport,
-                flow_src=t_src, flow_dst=t_dst, now=now,
-                window_s=threat_window_s, flow_slots=flow_slots,
-                flow_probe=flow_probe, stripe=threat_stripe)
+    with jax.named_scope("threat"):
+        if with_threat:
+            from ..threat.stage import threat_stage
+            t_src, t_dst = _flow_identities(tables.ep_identity,
+                                            pkt.endpoint, identity,
+                                            pkt.direction)
+            verdict, threat, threat_out, thr_drop, thr_redir, rl_drop = \
+                threat_stage(
+                    tables, threat, flows, verdict,
+                    identity=identity, dport=dport, proto=pkt.proto,
+                    tcp_flags=pkt.tcp_flags, length=pkt.length,
+                    is_fragment=pkt.is_fragment, established=established,
+                    saddr_w=pkt.saddr, daddr_w=daddr, sport=pkt.sport,
+                    flow_src=t_src, flow_dst=t_dst, now=now,
+                    window_s=threat_window_s, flow_slots=flow_slots,
+                    flow_probe=flow_probe, stripe=threat_stripe)
 
     # 8. Reply-path reverse NAT (lb.h lb4_rev_nat): restore VIP/port on
     # packets of flows whose CT entry carries a rev-NAT index.
-    from .conntrack import CT_REPLY, CT_RELATED
-    is_reply = (ct_verdict == CT_REPLY) | (ct_verdict == CT_RELATED)
-    rn = jnp.where(is_reply, ct_rev_nat, jnp.int32(0))
-    nat_saddr, nat_sport = lb_rev_nat_arrays(tables.lb, pkt.saddr,
-                                             pkt.sport, rn)
+    with jax.named_scope("revnat"):
+        from .conntrack import CT_REPLY, CT_RELATED
+        is_reply = (ct_verdict == CT_REPLY) | (ct_verdict == CT_RELATED)
+        rn = jnp.where(is_reply, ct_rev_nat, jnp.int32(0))
+        nat_saddr, nat_sport = lb_rev_nat_arrays(tables.lb, pkt.saddr,
+                                                 pkt.sport, rn)
 
-    event = jnp.where(
-        pf_hit, jnp.int32(DROP_PREFILTER),
-        jnp.where(verdict == VERDICT_DROP_FRAG, jnp.int32(DROP_FRAG_NOSUPPORT),
-                  jnp.where(verdict < 0, jnp.int32(DROP_POLICY),
-                            jnp.where(verdict > 0, jnp.int32(TRACE_TO_PROXY),
-                                      jnp.int32(TRACE_TO_LXC)))))
-    if with_l7_fast:
-        # VERDICT_DROP_L7 is produced only by the fast stage, so the
-        # final verdict identifies inline L7 denials exactly
-        event = jnp.where(verdict == jnp.int32(VERDICT_DROP_L7),
-                          jnp.int32(DROP_POLICY_L7), event)
-    if with_threat:
-        # VERDICT_DROP_THREAT likewise names the threat stage exactly
-        event = jnp.where(verdict == jnp.int32(VERDICT_DROP_THREAT),
-                          jnp.int32(DROP_THREAT), event)
+    with jax.named_scope("verdict"):
+        event = jnp.where(
+            pf_hit, jnp.int32(DROP_PREFILTER),
+            jnp.where(verdict == VERDICT_DROP_FRAG,
+                      jnp.int32(DROP_FRAG_NOSUPPORT),
+                      jnp.where(verdict < 0, jnp.int32(DROP_POLICY),
+                                jnp.where(verdict > 0,
+                                          jnp.int32(TRACE_TO_PROXY),
+                                          jnp.int32(TRACE_TO_LXC)))))
+        if with_l7_fast:
+            # VERDICT_DROP_L7 is produced only by the fast stage, so the
+            # final verdict identifies inline L7 denials exactly
+            event = jnp.where(verdict == jnp.int32(VERDICT_DROP_L7),
+                              jnp.int32(DROP_POLICY_L7), event)
+        if with_threat:
+            # VERDICT_DROP_THREAT likewise names the threat stage exactly
+            event = jnp.where(verdict == jnp.int32(VERDICT_DROP_THREAT),
+                              jnp.int32(DROP_THREAT), event)
 
     # 8.5 Fused traffic analytics (analytics/stage.py): fold the
     # batch's FINAL verdicts into the device-resident heavy-hitter
     # sketches / candidate key tables / cardinality registers — one
     # scatter-add per sketch plus one combined max-scatter.  Runs
     # post-threat so the drops metric attributes every drop arm.
-    if with_analytics:
-        from ..analytics.stage import analytics_stage
-        analytics = analytics_stage(
-            analytics, identity=identity, dport=dport, proto=pkt.proto,
-            sport=pkt.sport, length=pkt.length, verdict=verdict,
-            saddr_key=pkt.saddr, daddr_key=daddr, now=now,
-            depth=analytics_depth, lanes=analytics_lanes,
-            stripe=analytics_stripe)
+    with jax.named_scope("analytics"):
+        if with_analytics:
+            from ..analytics.stage import analytics_stage
+            analytics = analytics_stage(
+                analytics, identity=identity, dport=dport, proto=pkt.proto,
+                sport=pkt.sport, length=pkt.length, verdict=verdict,
+                saddr_key=pkt.saddr, daddr_key=daddr, now=now,
+                depth=analytics_depth, lanes=analytics_lanes,
+                stripe=analytics_stripe)
 
     # 9. Overlay encap (encap.h encap_and_redirect): allowed egress
     # packets whose (DNAT'd) destination falls in a peer node's pod
     # CIDR leave encapsulated to that node's tunnel endpoint, carrying
     # the sending endpoint's own identity (SECLABEL) in the tunnel key.
     # Proxy-redirected packets go to the proxy first, not the overlay.
-    zero = jnp.zeros_like(verdict)
-    if tun_probe > 0 and tables.tun_key_a is not None:
-        from .events import TRACE_TO_OVERLAY
-        t_hit, t_ep = lpm_lookup(tables.tun_masks, tables.tun_key_a,
-                                 tables.tun_key_b, tables.tun_value,
-                                 tables.tun_plens, daddr, tun_probe)
-        encap = t_hit & (pkt.direction == 1) & (verdict == 0) & ~pf_hit
-        if tables.ep_identity is not None:
-            n_ep = tables.ep_identity.shape[0]
-            src_sec = tables.ep_identity[
-                jnp.clip(pkt.endpoint, 0, n_ep - 1)]
+    with jax.named_scope("encap"):
+        zero = jnp.zeros_like(verdict)
+        if tun_probe > 0 and tables.tun_key_a is not None:
+            from .events import TRACE_TO_OVERLAY
+            t_hit, t_ep = lpm_lookup(tables.tun_masks, tables.tun_key_a,
+                                     tables.tun_key_b, tables.tun_value,
+                                     tables.tun_plens, daddr, tun_probe)
+            encap = t_hit & (pkt.direction == 1) & (verdict == 0) & ~pf_hit
+            if tables.ep_identity is not None:
+                n_ep = tables.ep_identity.shape[0]
+                src_sec = tables.ep_identity[
+                    jnp.clip(pkt.endpoint, 0, n_ep - 1)]
+            else:
+                src_sec = zero
+            tun_ep_out = jnp.where(encap, t_ep, zero)
+            tun_id_out = jnp.where(encap, src_sec, zero)
+            event = jnp.where(encap, jnp.int32(TRACE_TO_OVERLAY), event)
         else:
-            src_sec = zero
-        tun_ep_out = jnp.where(encap, t_ep, zero)
-        tun_id_out = jnp.where(encap, src_sec, zero)
-        event = jnp.where(encap, jnp.int32(TRACE_TO_OVERLAY), event)
-    else:
-        tun_ep_out = zero
-        tun_id_out = zero
+            tun_ep_out = zero
+            tun_id_out = zero
 
     nat = NATResult(daddr=daddr, dport=dport, saddr=nat_saddr,
                     sport=nat_sport, rev_nat=ct_rev_nat,
@@ -614,14 +629,15 @@ def full_datapath_step(tables: FullTables, ct, counters: Counters,
         # packet/byte counters + last-seen keyed by (src identity,
         # dst identity, DNAT'd dport, proto, event) — so host-side
         # observability reads compact aggregates, not packets.
-        from ..hubble.aggregation import flow_update_step
-        src_id, dst_id = _flow_identities(tables.ep_identity,
-                                          pkt.endpoint, identity,
-                                          pkt.direction)
-        flows = flow_update_step(
-            flows, src_id, dst_id, dport, pkt.proto, event,
-            pkt.length, now, slots=flow_slots, max_probe=flow_probe,
-            claim_budget=flow_claim_budget)
+        with jax.named_scope("flows"):
+            from ..hubble.aggregation import flow_update_step
+            src_id, dst_id = _flow_identities(tables.ep_identity,
+                                              pkt.endpoint, identity,
+                                              pkt.direction)
+            flows = flow_update_step(
+                flows, src_id, dst_id, dport, pkt.proto, event,
+                pkt.length, now, slots=flow_slots, max_probe=flow_probe,
+                claim_budget=flow_claim_budget)
         out = out + (flows,)
     if with_threat:
         # 10.5 Threat outputs: the updated shard-local state buffer
@@ -836,14 +852,15 @@ def full_datapath_step6(tables: FullTables6, ct, counters: Counters,
     b = pkt.sport.shape[0]
 
     # 1. Prefilter (bpf_xdp.c check_v6 analog).
-    if tables.pf6.kb.shape[0] > 0:
-        pf_hit, _ = lpm6_lookup(tables.pf6.masks, tables.pf6.k0,
-                                tables.pf6.k1, tables.pf6.k2,
-                                tables.pf6.k3, tables.pf6.kb,
-                                tables.pf6.value, tables.pf6.plens,
-                                pkt.saddr, pf6_probe)
-    else:
-        pf_hit = jnp.zeros(b, bool)
+    with jax.named_scope("prefilter"):
+        if tables.pf6.kb.shape[0] > 0:
+            pf_hit, _ = lpm6_lookup(tables.pf6.masks, tables.pf6.k0,
+                                    tables.pf6.k1, tables.pf6.k2,
+                                    tables.pf6.k3, tables.pf6.kb,
+                                    tables.pf6.value, tables.pf6.plens,
+                                    pkt.saddr, pf6_probe)
+        else:
+            pf_hit = jnp.zeros(b, bool)
 
     # 1.5 ICMPv6/NDP responder (bpf/lib/icmp6.h icmp6_handle, called
     # before LB/CT/policy on the from-container path bpf_lxc.c:403-408):
@@ -853,159 +870,170 @@ def full_datapath_step6(tables: FullTables6, ct, counters: Counters,
     # the router answers with an echo reply.  Every other ICMPv6 type
     # (NA, RS/RA, errors, echo to peers) flows on through CT + policy
     # like the reference's fall-through `return 0`.
-    is_icmp6 = pkt.proto == IPPROTO_ICMPV6
-    if tables.router_ip6 is not None and pkt.icmp_type is not None:
-        icmp_type = pkt.icmp_type
-        is_ns = is_icmp6 & (icmp_type == ICMP6_NS)
-        nd_target = pkt.nd_target if pkt.nd_target is not None \
-            else jnp.zeros_like(pkt.saddr)
-        target_is_router = jnp.all(
-            nd_target == tables.router_ip6[None, :], axis=1)
-        ns_answer = is_ns & target_is_router
-        ns_unknown = is_ns & ~target_is_router
-        echo_answer = is_icmp6 & (icmp_type == ICMP6_ECHO_REQUEST) & \
-            jnp.all(pkt.daddr == tables.router_ip6[None, :], axis=1)
-        icmp6_handled = ns_answer | ns_unknown | echo_answer
-    else:
-        icmp_type = jnp.zeros(b, jnp.int32)
-        ns_answer = ns_unknown = echo_answer = jnp.zeros(b, bool)
-        icmp6_handled = jnp.zeros(b, bool)
+    with jax.named_scope("lb"):
+        is_icmp6 = pkt.proto == IPPROTO_ICMPV6
+        if tables.router_ip6 is not None and pkt.icmp_type is not None:
+            icmp_type = pkt.icmp_type
+            is_ns = is_icmp6 & (icmp_type == ICMP6_NS)
+            nd_target = pkt.nd_target if pkt.nd_target is not None \
+                else jnp.zeros_like(pkt.saddr)
+            target_is_router = jnp.all(
+                nd_target == tables.router_ip6[None, :], axis=1)
+            ns_answer = is_ns & target_is_router
+            ns_unknown = is_ns & ~target_is_router
+            echo_answer = is_icmp6 & (icmp_type == ICMP6_ECHO_REQUEST) & \
+                jnp.all(pkt.daddr == tables.router_ip6[None, :], axis=1)
+            icmp6_handled = ns_answer | ns_unknown | echo_answer
+        else:
+            icmp_type = jnp.zeros(b, jnp.int32)
+            ns_answer = ns_unknown = echo_answer = jnp.zeros(b, bool)
+            icmp6_handled = jnp.zeros(b, bool)
 
-    # 2. Service LB DNAT (lb.h lb6_local).
-    if lb6_probe > 0 and tables.lb6 is not None:
-        daddr, dport, rev_nat, _is_svc = lb6_step(
-            tables.lb6, pkt.daddr, pkt.dport, pkt.proto, pkt.saddr,
-            pkt.sport, max_probe=lb6_probe)
-    else:
-        daddr, dport = pkt.daddr, pkt.dport
-        rev_nat = jnp.zeros(b, jnp.int32)
+        # 2. Service LB DNAT (lb.h lb6_local).
+        if lb6_probe > 0 and tables.lb6 is not None:
+            daddr, dport, rev_nat, _is_svc = lb6_step(
+                tables.lb6, pkt.daddr, pkt.dport, pkt.proto, pkt.saddr,
+                pkt.sport, max_probe=lb6_probe)
+        else:
+            daddr, dport = pkt.daddr, pkt.dport
+            rev_nat = jnp.zeros(b, jnp.int32)
 
     # 3. Conntrack on the DNAT'd folded tuple (separate v6 table).
-    ctb = CTBatch(saddr=fold6(pkt.saddr), daddr=fold6(daddr),
-                  sport=pkt.sport, dport=dport, proto=pkt.proto,
-                  direction=pkt.direction, tcp_flags=pkt.tcp_flags,
-                  related=jnp.zeros_like(pkt.proto))
+    with jax.named_scope("ct"):
+        ctb = CTBatch(saddr=fold6(pkt.saddr), daddr=fold6(daddr),
+                      sport=pkt.sport, dport=dport, proto=pkt.proto,
+                      direction=pkt.direction, tcp_flags=pkt.tcp_flags,
+                      related=jnp.zeros_like(pkt.proto))
 
     # 4. ipcache6: identity of the peer (src on ingress, dst on egress).
-    peer = jnp.where((pkt.direction == 0)[:, None], pkt.saddr, daddr)
-    if tables.ipcache6.kb.shape[0] > 0:
-        found, ident = lpm6_lookup(
-            tables.ipcache6.masks, tables.ipcache6.k0,
-            tables.ipcache6.k1, tables.ipcache6.k2, tables.ipcache6.k3,
-            tables.ipcache6.kb, tables.ipcache6.value,
-            tables.ipcache6.plens, peer, lpm6_probe)
-    else:
-        found = jnp.zeros(b, bool)
-        ident = jnp.zeros(b, jnp.int32)
-    identity = jnp.where(found, ident, jnp.int32(WORLD_IDENTITY))
-    if pkt.from_overlay is not None:
-        decap = (pkt.from_overlay != 0) & (pkt.direction == 0)
-        identity = jnp.where(decap, pkt.tunnel_id, identity)
-    if pkt.mark_identity is not None:
-        # proxy-mark re-entry (bpf_netdev.c:128-146), same as v4
-        identity = jnp.where(pkt.mark_identity > 0,
-                             pkt.mark_identity, identity)
+    with jax.named_scope("ipcache"):
+        peer = jnp.where((pkt.direction == 0)[:, None], pkt.saddr, daddr)
+        if tables.ipcache6.kb.shape[0] > 0:
+            found, ident = lpm6_lookup(
+                tables.ipcache6.masks, tables.ipcache6.k0,
+                tables.ipcache6.k1, tables.ipcache6.k2, tables.ipcache6.k3,
+                tables.ipcache6.kb, tables.ipcache6.value,
+                tables.ipcache6.plens, peer, lpm6_probe)
+        else:
+            found = jnp.zeros(b, bool)
+            ident = jnp.zeros(b, jnp.int32)
+        identity = jnp.where(found, ident, jnp.int32(WORLD_IDENTITY))
+        if pkt.from_overlay is not None:
+            decap = (pkt.from_overlay != 0) & (pkt.direction == 0)
+            identity = jnp.where(decap, pkt.tunnel_id, identity)
+        if pkt.mark_identity is not None:
+            # proxy-mark re-entry (bpf_netdev.c:128-146), same as v4
+            identity = jnp.where(pkt.mark_identity > 0,
+                                 pkt.mark_identity, identity)
 
     # 5. Policy verdict on the shared (family-agnostic) tables —
     # against the DNAT'd port, like the v4 path.
-    vb = PacketBatch(endpoint=pkt.endpoint, identity=identity,
-                     dport=dport, proto=pkt.proto,
-                     direction=pkt.direction, length=pkt.length,
-                     is_fragment=pkt.is_fragment)
-    if with_provenance or with_l7_fast:
-        pol_verdict, counters, pol_slot, pol_tier = verdict_step(
-            tables.key_id, tables.key_meta, tables.value, counters,
-            vb, policy_probe, count_mask=~icmp6_handled,
-            with_provenance=True)
-    else:
-        pol_verdict, counters = verdict_step(
-            tables.key_id, tables.key_meta, tables.value, counters, vb,
-            policy_probe, count_mask=~icmp6_handled)
+    with jax.named_scope("policy"):
+        vb = PacketBatch(endpoint=pkt.endpoint, identity=identity,
+                         dport=dport, proto=pkt.proto,
+                         direction=pkt.direction, length=pkt.length,
+                         is_fragment=pkt.is_fragment)
+        if with_provenance or with_l7_fast:
+            pol_verdict, counters, pol_slot, pol_tier = verdict_step(
+                tables.key_id, tables.key_meta, tables.value, counters,
+                vb, policy_probe, count_mask=~icmp6_handled,
+                with_provenance=True)
+        else:
+            pol_verdict, counters = verdict_step(
+                tables.key_id, tables.key_meta, tables.value, counters, vb,
+                policy_probe, count_mask=~icmp6_handled)
 
     # 5.5 On-device L7 fast verdict (same stage as the v4 family).
-    if with_l7_fast:
-        pol_verdict, l7_fast_allow, l7_fast_deny = _l7_fast_stage(
-            tables, payload, pol_verdict, pol_slot, k=l7_k, c1=l7_c1)
+    with jax.named_scope("l7"):
+        if with_l7_fast:
+            pol_verdict, l7_fast_allow, l7_fast_deny = _l7_fast_stage(
+                tables, payload, pol_verdict, pol_slot, k=l7_k, c1=l7_c1)
 
     # 6. CT step, creation gated on the verdict; new entries record the
     # flow's rev-NAT index so replies can restore the VIP.  Locally
     # answered ICMPv6 never creates CT state (the reply is synthesized,
     # not forwarded).
-    create_ok = (pol_verdict >= 0) & ~pf_hit & ~icmp6_handled
-    proxy_in = jnp.maximum(pol_verdict, 0)
-    ct_verdict, ct_rev_nat, ct_proxy, ct = ct_step(
-        ct, ctb, now, create_ok, update_mask=~pf_hit & ~icmp6_handled,
-        rev_nat_in=rev_nat, proxy_port_in=proxy_in,
-        slots=ct_slots, max_probe=ct_probe)
+    with jax.named_scope("ct"):
+        create_ok = (pol_verdict >= 0) & ~pf_hit & ~icmp6_handled
+        proxy_in = jnp.maximum(pol_verdict, 0)
+        ct_verdict, ct_rev_nat, ct_proxy, ct = ct_step(
+            ct, ctb, now, create_ok, update_mask=~pf_hit & ~icmp6_handled,
+            rev_nat_in=rev_nat, proxy_port_in=proxy_in,
+            slots=ct_slots, max_probe=ct_probe)
 
-    established = ct_verdict != CT_NEW
-    verdict = jnp.where(
-        pf_hit, jnp.int32(VERDICT_DROP),
-        jnp.where(ns_unknown, jnp.int32(VERDICT_DROP),
-                  jnp.where(ns_answer | echo_answer, jnp.int32(0),
-                            jnp.where(established, ct_proxy,
-                                      pol_verdict))))
+    with jax.named_scope("verdict"):
+        established = ct_verdict != CT_NEW
+        verdict = jnp.where(
+            pf_hit, jnp.int32(VERDICT_DROP),
+            jnp.where(ns_unknown, jnp.int32(VERDICT_DROP),
+                      jnp.where(ns_answer | echo_answer, jnp.int32(0),
+                                jnp.where(established, ct_proxy,
+                                          pol_verdict))))
 
     # 6.5 Inline threat scoring (same fused stage as the v4 family;
     # addresses enter the tuple hash as their CT folds).  Locally
     # answered ICMPv6 rows are scored but exempt from overrides — the
     # responder's reply is synthesized, not forwarded.
-    if with_threat:
-        from ..threat.stage import threat_stage
-        t_src, t_dst = _flow_identities(tables.ep_identity,
-                                        pkt.endpoint, identity,
-                                        pkt.direction)
-        verdict, threat, threat_out, thr_drop, thr_redir, rl_drop = \
-            threat_stage(
-                tables, threat, flows, verdict,
-                identity=identity, dport=dport, proto=pkt.proto,
-                tcp_flags=pkt.tcp_flags, length=pkt.length,
-                is_fragment=pkt.is_fragment, established=established,
-                saddr_w=ctb.saddr, daddr_w=ctb.daddr, sport=pkt.sport,
-                flow_src=t_src, flow_dst=t_dst, now=now,
-                window_s=threat_window_s, flow_slots=flow_slots,
-                flow_probe=flow_probe, stripe=threat_stripe,
-                exempt=icmp6_handled)
+    with jax.named_scope("threat"):
+        if with_threat:
+            from ..threat.stage import threat_stage
+            t_src, t_dst = _flow_identities(tables.ep_identity,
+                                            pkt.endpoint, identity,
+                                            pkt.direction)
+            verdict, threat, threat_out, thr_drop, thr_redir, rl_drop = \
+                threat_stage(
+                    tables, threat, flows, verdict,
+                    identity=identity, dport=dport, proto=pkt.proto,
+                    tcp_flags=pkt.tcp_flags, length=pkt.length,
+                    is_fragment=pkt.is_fragment, established=established,
+                    saddr_w=ctb.saddr, daddr_w=ctb.daddr, sport=pkt.sport,
+                    flow_src=t_src, flow_dst=t_dst, now=now,
+                    window_s=threat_window_s, flow_slots=flow_slots,
+                    flow_probe=flow_probe, stripe=threat_stripe,
+                    exempt=icmp6_handled)
 
     # 7. Reply-path reverse NAT (lb6_rev_nat).
-    from .conntrack import CT_RELATED, CT_REPLY
-    is_reply = (ct_verdict == CT_REPLY) | (ct_verdict == CT_RELATED)
-    rn = jnp.where(is_reply, ct_rev_nat, jnp.int32(0))
-    if tables.lb6 is not None:
-        nat_saddr, nat_sport = lb6_rev_nat(tables.lb6, pkt.saddr,
-                                           pkt.sport, rn)
-    else:
-        nat_saddr, nat_sport = pkt.saddr, pkt.sport
+    with jax.named_scope("revnat"):
+        from .conntrack import CT_RELATED, CT_REPLY
+        is_reply = (ct_verdict == CT_REPLY) | (ct_verdict == CT_RELATED)
+        rn = jnp.where(is_reply, ct_rev_nat, jnp.int32(0))
+        if tables.lb6 is not None:
+            nat_saddr, nat_sport = lb6_rev_nat(tables.lb6, pkt.saddr,
+                                               pkt.sport, rn)
+        else:
+            nat_saddr, nat_sport = pkt.saddr, pkt.sport
 
-    event = jnp.where(
-        pf_hit, jnp.int32(DROP_PREFILTER),
-        jnp.where(ns_answer, jnp.int32(ICMP6_NS_REPLY),
-        jnp.where(echo_answer, jnp.int32(ICMP6_ECHO_REPLY),
-        jnp.where(ns_unknown, jnp.int32(DROP_UNKNOWN_TARGET),
-        jnp.where(verdict == VERDICT_DROP_FRAG,
-                  jnp.int32(DROP_FRAG_NOSUPPORT),
-                  jnp.where(verdict < 0, jnp.int32(DROP_POLICY),
-                            jnp.where(verdict > 0,
-                                      jnp.int32(TRACE_TO_PROXY),
-                                      jnp.int32(TRACE_TO_LXC))))))))
-    if with_l7_fast:
-        event = jnp.where(verdict == jnp.int32(VERDICT_DROP_L7),
-                          jnp.int32(DROP_POLICY_L7), event)
-    if with_threat:
-        event = jnp.where(verdict == jnp.int32(VERDICT_DROP_THREAT),
-                          jnp.int32(DROP_THREAT), event)
+    with jax.named_scope("verdict"):
+        event = jnp.where(
+            pf_hit, jnp.int32(DROP_PREFILTER),
+            jnp.where(ns_answer, jnp.int32(ICMP6_NS_REPLY),
+            jnp.where(echo_answer, jnp.int32(ICMP6_ECHO_REPLY),
+            jnp.where(ns_unknown, jnp.int32(DROP_UNKNOWN_TARGET),
+            jnp.where(verdict == VERDICT_DROP_FRAG,
+                      jnp.int32(DROP_FRAG_NOSUPPORT),
+                      jnp.where(verdict < 0, jnp.int32(DROP_POLICY),
+                                jnp.where(verdict > 0,
+                                          jnp.int32(TRACE_TO_PROXY),
+                                          jnp.int32(TRACE_TO_LXC))))))))
+        if with_l7_fast:
+            event = jnp.where(verdict == jnp.int32(VERDICT_DROP_L7),
+                              jnp.int32(DROP_POLICY_L7), event)
+        if with_threat:
+            event = jnp.where(verdict == jnp.int32(VERDICT_DROP_THREAT),
+                              jnp.int32(DROP_THREAT), event)
 
     # 7.5 Fused traffic analytics (same stage as the v4 family; the
     # address words enter the flow hash and dst-prefix key as their CT
     # folds — deterministic, shared with the oracle).
-    if with_analytics:
-        from ..analytics.stage import analytics_stage
-        analytics = analytics_stage(
-            analytics, identity=identity, dport=dport, proto=pkt.proto,
-            sport=pkt.sport, length=pkt.length, verdict=verdict,
-            saddr_key=ctb.saddr, daddr_key=ctb.daddr, now=now,
-            depth=analytics_depth, lanes=analytics_lanes,
-            stripe=analytics_stripe)
+    with jax.named_scope("analytics"):
+        if with_analytics:
+            from ..analytics.stage import analytics_stage
+            analytics = analytics_stage(
+                analytics, identity=identity, dport=dport, proto=pkt.proto,
+                sport=pkt.sport, length=pkt.length, verdict=verdict,
+                saddr_key=ctb.saddr, daddr_key=ctb.daddr, now=now,
+                depth=analytics_depth, lanes=analytics_lanes,
+                stripe=analytics_stripe)
     nat = NAT6Result(daddr=daddr, dport=dport, saddr=nat_saddr,
                      sport=nat_sport, rev_nat=ct_rev_nat)
     out = (verdict, event, identity, nat, ct, counters)
@@ -1014,14 +1042,15 @@ def full_datapath_step6(tables: FullTables6, ct, counters: Counters,
         # based, so the table is family-agnostic like the policy
         # tables; locally answered ICMPv6 still aggregates, under its
         # reply event code).
-        from ..hubble.aggregation import flow_update_step
-        src_id, dst_id = _flow_identities(tables.ep_identity,
-                                          pkt.endpoint, identity,
-                                          pkt.direction)
-        flows = flow_update_step(
-            flows, src_id, dst_id, dport, pkt.proto, event,
-            pkt.length, now, slots=flow_slots, max_probe=flow_probe,
-            claim_budget=flow_claim_budget)
+        with jax.named_scope("flows"):
+            from ..hubble.aggregation import flow_update_step
+            src_id, dst_id = _flow_identities(tables.ep_identity,
+                                              pkt.endpoint, identity,
+                                              pkt.direction)
+            flows = flow_update_step(
+                flows, src_id, dst_id, dport, pkt.proto, event,
+                pkt.length, now, slots=flow_slots, max_probe=flow_probe,
+                claim_budget=flow_claim_budget)
         out = out + (flows,)
     if with_threat:
         out = out + (threat, threat_out)

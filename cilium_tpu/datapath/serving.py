@@ -55,6 +55,15 @@ Sync-point discipline: the ONLY device synchronization on this path is
 the ticket-completion transfer in ``_finalize`` (flagged as a blocking
 boundary in ``pipeline_stage_seconds{stage="complete"}``); the lint in
 tests/test_sync_lint.py holds the hot modules to that.
+
+Telemetry (``observability/stages.py``), per launch: ``queue-wait``
+(the oldest frame's wait), ``dispatch`` (with ``pack`` inside it),
+``complete`` and, inside it, ``handoff`` (the two thread hops to and
+from the supervisor's watchdog worker), ``d2h`` (the device->host copy,
+on that worker) and ``resolve`` (tickets, their callbacks, SLO and
+verdict-outcome accounting).  Each span carries the launch number
+(``launch=n``, the lane's ``batches`` count), as does every ticket of
+the launch (``Ticket.launch``).
 """
 
 from __future__ import annotations
@@ -69,9 +78,11 @@ import numpy as np
 from ..observability.events import (EVENT_SERVING_OVERLOAD,
                                     recorder as flight_recorder)
 from ..observability.slo import slo_tracker
-from ..observability.stages import record_stage
+from ..observability.stages import NO_SPAN, record_stage
+from ..observability.stages import stage as span
 from ..utils.bucketing import bucket_size
-from ..utils.metrics import DATAPLANE_OVERLOADED, registry
+from ..utils.metrics import (DATAPLANE_OVERLOADED, count_policy_verdicts,
+                             registry)
 from .events import DROP_POLICY
 # the packed staging row order, unpacked by full_datapath_step_packed
 # inside the fused program; the names also match the
@@ -107,13 +118,16 @@ class Ticket:
     results plus the error that caused them)."""
 
     __slots__ = ("_event", "value", "error", "submitted_at",
-                 "deadline", "_callbacks", "_cb_lock")
+                 "deadline", "launch", "_callbacks", "_cb_lock")
 
     def __init__(self, deadline: Optional[float] = None):
         self._event = threading.Event()
         self.value = None
         self.error: Optional[BaseException] = None
         self.submitted_at = time.perf_counter()
+        # the launch that answered it (the lane's batch number; None
+        # when it never reached the device)
+        self.launch: Optional[int] = None
         # absolute monotonic deadline: unserved work older than this
         # is shed at drain time (admission control), never dispatched
         self.deadline = None if deadline is None else \
@@ -198,7 +212,8 @@ class ContinuousDispatcher:
         self._telemetry = telemetry
         self._cond = threading.Condition()
         self._pending: "deque[Tuple[object, Ticket]]" = deque()
-        self._inflight: "deque[Tuple[object, list, list]]" = deque()
+        # (handle, batch, weights, launch number)
+        self._inflight: "deque[Tuple[object, list, list, int]]" = deque()
         self._closed = False
         # ---- admission control: weight-bounded pending queue with a
         # hysteresis overload watermark pair (None = unbounded, the
@@ -221,6 +236,7 @@ class ContinuousDispatcher:
         self._shard = getattr(supervisor, "shard", None)
         # observability: how well the batching is working
         self.batches = 0
+        self._launch_no = 0   # the launch being issued (batches + 1)
         self.frames = 0
         self.items_total = 0
         self.max_batch_seen = 0
@@ -354,26 +370,16 @@ class ContinuousDispatcher:
 
     def _launch_batch(self, batch, total: int) -> None:
         telem = self._telemetry()
+        n = self._launch_no = self.batches + 1
         t0 = time.perf_counter() if telem else 0.0
-        items = [item for item, _t in batch]
-        if self.supervisor is not None:
-            on_device, payload = self.supervisor.launch(
-                self._launch, items, total)
-            if not on_device:
-                self._resolve_static(batch, payload)
-                return
-            handle = payload
-        else:
-            try:
-                handle = self._launch(items, total)
-            except Exception as e:  # noqa: BLE001 — fail closed: deny
-                self._fail(batch, e)   # exactly this batch's frames
-                return
+        with span(self.family, "dispatch", launch=n) if telem \
+                else NO_SPAN:
+            handle = self._issue(batch, total)
+        if handle is None:
+            return
         if telem:
             record_stage(self.family, "queue-wait",
                          t0 - batch[0][1].submitted_at)
-            record_stage(self.family, "dispatch",
-                         time.perf_counter() - t0)
         # SLO flight sample: queue state as of this launch (racy reads
         # are fine — observability, not control flow)
         slo_tracker.sample_queue(self.lane, queued=len(self._pending),
@@ -381,7 +387,8 @@ class ContinuousDispatcher:
                                  pending_weight=self._pending_weight,
                                  shard=self._shard)
         self._inflight.append(
-            (handle, batch, [self._weight(item) for item, _t in batch]))
+            (handle, batch, [self._weight(item) for item, _t in batch],
+             n))
         self.batches += 1
         self.frames += len(batch)
         self.items_total += total
@@ -389,32 +396,67 @@ class ContinuousDispatcher:
         SERVING_BATCHES.inc(labels={"lane": self.lane})
         SERVING_FRAMES.inc(len(batch), labels={"lane": self.lane})
 
+    def _issue(self, batch, total: int):
+        """Launch one batch: its in-flight handle, or None when the
+        batch was answered otherwise (fail-static or fail-closed)."""
+        items = [item for item, _t in batch]
+        if self.supervisor is not None:
+            on_device, payload = self.supervisor.launch(
+                self._launch, items, total)
+            if not on_device:
+                self._resolve_static(batch, payload)
+                return None
+            return payload
+        try:
+            return self._launch(items, total)
+        except Exception as e:  # noqa: BLE001 — fail closed: deny
+            self._fail(batch, e)   # exactly this batch's frames
+            return None
+
     def _complete_oldest(self) -> None:
-        handle, batch, weights = self._inflight.popleft()
+        handle, batch, weights, n = self._inflight.popleft()
         telem = self._telemetry()
-        t0 = time.perf_counter() if telem else 0.0
+        # the one blocking boundary on this path: host waits out device
+        # compute for the batch launched one step earlier
+        with span(self.family, "complete", launch=n) if telem \
+                else NO_SPAN:
+            results = self._collect(handle, batch, weights)
+        if results is None:
+            return
+        handoff = self.supervisor.handoff_s \
+            if telem and self.supervisor is not None else None
+        if handoff is not None:
+            record_stage(self.family, "handoff", handoff)
+        with span(self.family, "resolve", launch=n) if telem \
+                else NO_SPAN:
+            if telem:
+                self._account(results)
+            for (item, ticket), res in zip(batch, results):
+                ticket.launch = n
+                ticket.resolve(res)
+            self._observe_slo(batch)
+
+    def _collect(self, handle, batch, weights):
+        """One launch's per-item results, or None when the batch was
+        answered otherwise (fail-static or fail-closed)."""
         if self.supervisor is not None:
             ok, payload = self.supervisor.finalize(
                 self._finalize, handle, weights,
                 [item for item, _t in batch])
             if not ok:
                 self._resolve_static(batch, payload)
-                return
-            results = payload
-        else:
-            try:
-                results = self._finalize(handle, weights)
-            except Exception as e:  # noqa: BLE001 — fail closed: deny
-                self._fail(batch, e)   # exactly this batch's frames
-                return
-        if telem:
-            # the one blocking boundary on this path: host waits out
-            # device compute for the batch launched one step earlier
-            record_stage(self.family, "complete",
-                         time.perf_counter() - t0)
-        for (item, ticket), res in zip(batch, results):
-            ticket.resolve(res)
-        self._observe_slo(batch)
+                return None
+            return payload
+        try:
+            return self._finalize(handle, weights)
+        except Exception as e:  # noqa: BLE001 — fail closed: deny
+            self._fail(batch, e)   # exactly this batch's frames
+            return None
+
+    def _account(self, results) -> None:
+        """Telemetry over one device launch's results, before its
+        tickets resolve (nothing here; lanes with countable results
+        override it)."""
 
     def _observe_slo(self, batch) -> None:
         """Feed resolved tickets into the serving SLO tier: one
@@ -478,6 +520,13 @@ class ContinuousDispatcher:
             self._closed = True
             self._cond.notify_all()
         self._thread.join(timeout=timeout)
+
+
+class _LaunchResults(list):
+    """One launch's per-frame (verdict, identity) slices, with the
+    launch's whole host verdict array they were cut from."""
+
+    __slots__ = ("verdicts",)
 
 
 class VerdictDispatcher(ContinuousDispatcher):
@@ -559,8 +608,17 @@ class VerdictDispatcher(ContinuousDispatcher):
         return ring[tick % len(ring)]
 
     def _launch_records(self, items, total: int):
-        telem = self._telemetry()
-        t0 = time.perf_counter() if telem else 0.0
+        n = self._launch_no
+        with span(self.family, "pack", launch=n) if self._telemetry() \
+                else NO_SPAN:
+            stage, pstage = self._pack(items, total)
+        verdict, _event, identity, _nat = \
+            self._datapath.process_packed(stage, payload=pstage)
+        return verdict, identity, n
+
+    def _pack(self, items, total: int):
+        """The launch's [10, rows] field matrix (and [rows, W] payload
+        matrix when the engine has L7 fast verdicts on)."""
         rows = bucket_size(total, self._min_rows)
         stage = self._stage_for(rows)
         width = 0
@@ -596,24 +654,28 @@ class VerdictDispatcher(ContinuousDispatcher):
             # pad payloads stay absent: a duplicated header row with a
             # real payload could flip the pad's verdict arm
             pstage[total:rows] = -1
-        if telem:
-            record_stage(self.family, "pack",
-                         time.perf_counter() - t0)
-        verdict, _event, identity, _nat = \
-            self._datapath.process_packed(stage, payload=pstage)
-        return verdict, identity
+        return stage, pstage
 
     def _finalize_records(self, handle, weights: Sequence[int]):
-        verdict, identity = handle
+        verdict, identity, n = handle
         total = sum(weights)
-        v = np.asarray(verdict)[:total].astype(np.int32)   # sync-ok: the serving path's one blocking boundary (stage="complete")
-        i = np.asarray(identity)[:total].astype(np.int32)  # sync-ok: same transfer, already realized by the line above
-        out = []
+        d2h = span(self.family, "d2h", launch=n) \
+            if self._telemetry() else NO_SPAN
+        with d2h:
+            v = np.asarray(verdict)[:total].astype(np.int32)   # sync-ok: the serving path's one blocking boundary (stage="complete")
+            i = np.asarray(identity)[:total].astype(np.int32)  # sync-ok: same transfer, already realized by the line above
+        out = _LaunchResults()
+        out.verdicts = v
         off = 0
         for w in weights:
             out.append((v[off:off + w], i[off:off + w]))
             off += w
         return out
+
+    def _account(self, results) -> None:
+        """``policy_verdicts_total`` from the launch's host copy (the
+        engine does not read the verdicts a second time)."""
+        count_policy_verdicts(results.verdicts)
 
     @staticmethod
     def _deny_records(item):

@@ -55,6 +55,7 @@ from ..observability.events import (EVENT_DATAPLANE_DEGRADED,
                                     EVENT_DATAPLANE_RECOVERED,
                                     EVENT_DATAPLANE_TRIP,
                                     recorder as flight_recorder)
+from ..observability.stages import NO_SPAN, stage
 from ..utils.faultinject import DeviceLaneFault
 from ..utils.metrics import (DATAPLANE_DEVICE_FAULTS,
                              DATAPLANE_FAIL_STATIC, DATAPLANE_MODE,
@@ -149,16 +150,30 @@ class HostStaticOracle:
     def refresh(self) -> bool:
         """Rebuild the host view from the live engine.  Returns False
         (keeping the previous view) when the device CT cannot be read
-        — a dead device must not wipe the last-known-good state."""
+        — a dead device must not wipe the last-known-good state.
+
+        Timed as the ``supervisor`` stage ``oracle-refresh``, with its
+        parts ``copy-states``, ``compile-lpm``, ``snapshot-ct`` (holds
+        the engine lock, so it stalls dispatch) and ``decode-ct``."""
         dp = self.datapath
-        states = {int(s): st for s, st in
-                  (dp.host_policy_states() or {}).items()}
-        lpm = self._compile_host_lpm(dict(dp.ipcache_prefixes))
-        try:
-            snap, _snap6 = dp.snapshot_ct()
-            ct = self._decode_ct(snap)
-        except Exception:  # noqa: BLE001 — device read failed: keep
-            ct = None      # the last good CT view
+        telem = getattr(dp, "telemetry_enabled", False)
+
+        def part(name):
+            return stage("supervisor", name) if telem else NO_SPAN
+
+        with part("oracle-refresh"):
+            with part("copy-states"):
+                states = {int(s): st for s, st in
+                          (dp.host_policy_states() or {}).items()}
+            with part("compile-lpm"):
+                lpm = self._compile_host_lpm(dict(dp.ipcache_prefixes))
+            try:
+                with part("snapshot-ct"):
+                    snap, _snap6 = dp.snapshot_ct()
+                with part("decode-ct"):
+                    ct = self._decode_ct(snap)
+            except Exception:  # noqa: BLE001 — device read failed:
+                ct = None      # keep the last good CT view
         with self._mu:
             self._states = states
             self._lpm = lpm
@@ -270,25 +285,31 @@ class _WatchdogRunner:
         self._req: "queue.SimpleQueue" = queue.SimpleQueue()
         self._resp: "queue.SimpleQueue" = queue.SimpleQueue()
         self.abandoned = False
+        # the last call's two thread hops: the request's wait for the
+        # worker plus the answer's wait for the caller (seconds)
+        self.handoff_s: Optional[float] = None
         self._thread = threading.Thread(target=self._loop, daemon=True,
                                         name=name)
         self._thread.start()
 
     def _loop(self) -> None:
         while True:
-            gen, fn = self._req.get()
+            gen, fn, t_put = self._req.get()
             if fn is None:
                 return
+            t_start = time.perf_counter()
             try:
                 out = ("ok", fn())
             except BaseException as e:  # noqa: BLE001 — classified
                 out = ("error", e)      # by the supervisor
-            self._resp.put((gen, out))
+            self._resp.put((gen, out, t_start - t_put,
+                            time.perf_counter()))
 
     def run(self, fn: Callable, timeout: float):
         """("ok", result) | ("error", exc) | ("hung", None)."""
         gen = time.monotonic_ns()
-        self._req.put((gen, fn))
+        self.handoff_s = None
+        self._req.put((gen, fn, time.perf_counter()))
         deadline = time.monotonic() + timeout
         while True:
             remaining = deadline - time.monotonic()
@@ -296,16 +317,18 @@ class _WatchdogRunner:
                 self.abandoned = True
                 return ("hung", None)
             try:
-                got_gen, out = self._resp.get(timeout=remaining)
+                got_gen, out, hop_in, t_end = \
+                    self._resp.get(timeout=remaining)
             except queue.Empty:
                 self.abandoned = True
                 return ("hung", None)
             if got_gen == gen:
+                self.handoff_s = hop_in + time.perf_counter() - t_end
                 return out
             # stale result from a call a previous owner abandoned
 
     def close(self) -> None:
-        self._req.put((0, None))
+        self._req.put((0, None, 0.0))
 
 
 # --------------------------------------------------------------------------
@@ -443,6 +466,12 @@ class DeviceSupervisor:
                 return False, (None, e)
             self._on_fault("launch", e)
             return False, self._serve_static(items, total)
+
+    @property
+    def handoff_s(self) -> Optional[float]:
+        """The last finalize's thread hops to and from the watchdog
+        worker (None: it ran inline, or hung)."""
+        return None if self._runner is None else self._runner.handoff_s
 
     def finalize(self, finalize_fn: Callable, handle, weights, items):
         hook = self._hook

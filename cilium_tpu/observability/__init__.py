@@ -15,11 +15,13 @@ lightweight span tracer for control-plane causality:
                      ``policy_implementation_delay_seconds`` histogram
                      plus a per-revision span tree.
 - ``jitstats``     — JIT/compile telemetry (compile count/seconds,
-                     jit-cache hit/miss, live device bytes) captured
-                     around every jitted entry point.
+                     persistent-cache hit/miss, live device bytes)
+                     counted from JAX's own compile events.
 - ``stages``       — host-timed pipeline stage slices and blocking
                      boundaries, exported as histograms and
-                     ``pipeline_report()``.
+                     ``pipeline_report()``; ``stage()`` also puts the
+                     slice on a ``jax.profiler`` trace, and garbage
+                     collections are timed by generation.
 - ``pressure``     — map-pressure gauges + warning thresholds for
                      every device table (pkg/metrics BPFMapPressure
                      analog).
@@ -38,7 +40,8 @@ from .tracer import Span, SpanContext, Tracer, tracer
 from .propagation import (POLICY_IMPLEMENTATION_DELAY,
                           PolicyPropagationTracker)
 from .jitstats import JitTelemetry, jit_telemetry
-from .stages import PIPELINE_STAGE_SECONDS, pipeline_report, record_stage
+from .stages import (NO_SPAN, PIPELINE_STAGE_SECONDS, pipeline_report,
+                     record_stage, stage)
 from .pressure import MAP_PRESSURE, compute_pressure
 from .events import (DEGRADED_SIGNALS, EVENT_TYPES, FlightEvent,
                      FlightRecorder, recorder)
@@ -48,7 +51,8 @@ __all__ = [
     "Span", "SpanContext", "Tracer", "tracer",
     "POLICY_IMPLEMENTATION_DELAY", "PolicyPropagationTracker",
     "JitTelemetry", "jit_telemetry",
-    "PIPELINE_STAGE_SECONDS", "pipeline_report", "record_stage",
+    "NO_SPAN", "PIPELINE_STAGE_SECONDS", "pipeline_report",
+    "record_stage", "stage",
     "MAP_PRESSURE", "compute_pressure",
     "DEGRADED_SIGNALS", "EVENT_TYPES", "FlightEvent",
     "FlightRecorder", "recorder",

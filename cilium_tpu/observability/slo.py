@@ -34,7 +34,8 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Dict, List, Optional
+from collections import deque
+from typing import Deque, Dict, List, Optional
 
 from ..utils.metrics import registry
 
@@ -88,7 +89,8 @@ class _LaneSLO:
     """One lane's rolling state (lock held by the tracker)."""
 
     __slots__ = ("lane", "shard", "objective", "requests", "breaches",
-                 "latencies", "outcomes", "queue_ring", "worst")
+                 "latencies", "outcomes", "window_breaches",
+                 "queue_ring", "worst")
 
     def __init__(self, lane: str, shard: Optional[int],
                  objective: float):
@@ -97,9 +99,10 @@ class _LaneSLO:
         self.objective = objective
         self.requests = 0
         self.breaches = 0
-        self.latencies: List[float] = []   # bounded reservoir
-        self.outcomes: List[bool] = []     # bounded breach window
-        self.queue_ring: List[Dict] = []   # bounded flight samples
+        self.latencies: Deque[float] = deque(maxlen=RESERVOIR)
+        self.outcomes: Deque[bool] = deque(maxlen=WINDOW)
+        self.window_breaches = 0           # sum(outcomes), kept running
+        self.queue_ring: Deque[Dict] = deque(maxlen=QUEUE_RING)
         self.worst = 0.0
 
 
@@ -148,12 +151,11 @@ class SLOTracker:
             if breach:
                 st.breaches += 1
             st.latencies.append(latency_s)
-            if len(st.latencies) > RESERVOIR:
-                del st.latencies[:len(st.latencies) - RESERVOIR]
+            if len(st.outcomes) == WINDOW:
+                st.window_breaches -= st.outcomes[0]   # about to drop
             st.outcomes.append(breach)
-            if len(st.outcomes) > WINDOW:
-                del st.outcomes[:len(st.outcomes) - WINDOW]
-            burn = (sum(st.outcomes) / len(st.outcomes)) \
+            st.window_breaches += breach
+            burn = (st.window_breaches / len(st.outcomes)) \
                 / self.error_budget
         SERVING_SLO_LATENCY.observe(latency_s, labels={"lane": lane})
         SERVING_SLO_REQUESTS.inc(labels={"lane": lane})
@@ -170,8 +172,6 @@ class SLOTracker:
             st.queue_ring.append({
                 "t": time.time(), "queued": queued,
                 "inflight": inflight, "pending": pending_weight})
-            if len(st.queue_ring) > QUEUE_RING:
-                del st.queue_ring[:len(st.queue_ring) - QUEUE_RING]
         SERVING_SLO_QUEUE.set(float(pending_weight),
                               labels={"lane": lane})
         SERVING_SLO_INFLIGHT.set(float(inflight), labels={"lane": lane})
@@ -187,7 +187,7 @@ class SLOTracker:
             for name, st in sorted(self._lanes.items()):
                 lat = sorted(st.latencies)
                 window = len(st.outcomes)
-                breach_frac = (sum(st.outcomes) / window) if window \
+                breach_frac = (st.window_breaches / window) if window \
                     else 0.0
                 last_q = st.queue_ring[-1] if st.queue_ring else None
                 lanes[name] = {

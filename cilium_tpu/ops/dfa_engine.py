@@ -45,7 +45,6 @@ the oracle.
 from __future__ import annotations
 
 import functools
-import time
 from dataclasses import dataclass
 from typing import Optional
 
@@ -184,6 +183,8 @@ class DFAEngine:
                  stride_budget: Optional[int] = None,
                  dtype: Optional[np.dtype] = None,
                  on_accel: Optional[bool] = None):
+        from ..observability.jitstats import jit_telemetry
+        jit_telemetry.attach()   # its match programs' compiles count
         self.compiled = compiled
         self.max_len = int(max_len)
         self.batch_hint = int(batch_hint)
@@ -293,11 +294,6 @@ class DFAEngine:
         if isinstance(data, PackedBatch):
             return self.match_encoded(data)
         data = jnp.asarray(data)
-        # jit-cache telemetry (observability/jitstats): the dispatch
-        # slice is timed and the first call per (engine, strategy,
-        # geometry) is classified as a compile
-        from ..observability.jitstats import jit_telemetry
-        t0 = time.perf_counter() if jit_telemetry.enabled else 0.0
         if self.strategy == "stride":
             out = _stride_match(self.k, self._c1, self._flat,
                                 self._map, self._accept, self._starts,
@@ -308,10 +304,6 @@ class DFAEngine:
         else:
             out = _assoc_match(self._table_q, self._accept,
                                self._starts, data)
-        if jit_telemetry.enabled:
-            jit_telemetry.record(
-                f"dfa.match-{self.strategy}", id(self),
-                tuple(data.shape), time.perf_counter() - t0)
         return out
 
     def match_encoded(self, packed: PackedBatch) -> jnp.ndarray:

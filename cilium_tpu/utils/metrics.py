@@ -9,6 +9,7 @@ Prometheus text format at /metrics.
 
 from __future__ import annotations
 
+import bisect
 import threading
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -121,6 +122,8 @@ class Histogram(_Metric):
                  buckets: Sequence[float] = DEFAULT_BUCKETS):
         super().__init__(name, help_text)
         self.buckets = tuple(sorted(buckets))
+        # per bucket (not cumulative): an observation touches one slot;
+        # expose() accumulates
         self._counts: Dict[_LabelKey, List[int]] = {}
         self._sums: Dict[_LabelKey, float] = {}
         self._totals: Dict[_LabelKey, int] = {}
@@ -136,12 +139,13 @@ class Histogram(_Metric):
         grouped by distinct value) without a Python loop per packet."""
         key = _lk(labels)
         count = int(count)
+        slot = bisect.bisect_left(self.buckets, value)
         with self._lock:
-            counts = self._counts.setdefault(
-                key, [0] * len(self.buckets))
-            for i, ub in enumerate(self.buckets):
-                if value <= ub:
-                    counts[i] += count
+            counts = self._counts.get(key)
+            if counts is None:
+                counts = self._counts[key] = [0] * len(self.buckets)
+            if slot < len(counts) and value <= self.buckets[slot]:
+                counts[slot] += count
             self._sums[key] = self._sums.get(key, 0.0) + value * count
             self._totals[key] = self._totals.get(key, 0) + count
 
@@ -169,7 +173,9 @@ class Histogram(_Metric):
             items = sorted(self._counts.items()) or \
                 [(_lk(None), [0] * len(self.buckets))]
             for key, counts in items:
-                for ub, c in zip(self.buckets, counts):
+                c = 0
+                for ub, n in zip(self.buckets, counts):
+                    c += n
                     lk = key + (("le", repr(ub)),)
                     out.append(f"{self.name}_bucket{_fmt_labels(lk)} {c}")
                 total = self._totals.get(key, 0)
@@ -253,6 +259,18 @@ POLICY_IMPORT_ERRORS = registry.counter(
     "policy_import_errors", "Count of failed policy imports")
 POLICY_VERDICTS = registry.counter(
     "policy_verdicts_total", "Datapath verdicts by outcome")
+
+
+def count_policy_verdicts(verdicts) -> None:
+    """Add one batch's host verdict array to ``policy_verdicts_total``
+    (<0 denied, >0 redirected, 0 allowed)."""
+    denied = int((verdicts < 0).sum())
+    redirected = int((verdicts > 0).sum())
+    allowed = verdicts.shape[0] - denied - redirected
+    for outcome, n in (("allowed", allowed), ("denied", denied),
+                       ("redirected", redirected)):
+        if n:
+            POLICY_VERDICTS.inc(n, labels={"outcome": outcome})
 
 # Verdict provenance series (datapath/events.py TIER_*): which stage
 # of the compiled pipeline decided, which compiled entries are doing

@@ -155,6 +155,33 @@ class TestSLOTracker:
         assert snap["objective-ms"] == 10.0
         assert snap["breaches"] == 1
 
+    def test_running_breach_count_matches_the_window_sum(self):
+        """The burn rate kept from a running breach count equals the
+        one summed over the outcome window, after every observation of
+        a random stream long enough to roll the window over."""
+        import random
+
+        from cilium_tpu.observability.slo import (SERVING_SLO_BURN,
+                                                  WINDOW)
+        rng = random.Random(23)
+        slo = SLOTracker()
+        slo.configure(objective_s=0.010, error_budget=0.01)
+        lane = f"lane-burn-{time.monotonic_ns()}"
+        outcomes = []
+        for _ in range(3 * WINDOW + 17):
+            lat = rng.choice((0.001, 0.002, 0.020, 0.5)) \
+                if rng.random() < 0.3 else 0.004
+            slo.observe(lane, lat)
+            outcomes.append(lat > 0.010)
+            window = outcomes[-WINDOW:]
+            want = round(sum(window) / len(window) / 0.01, 4)
+            assert SERVING_SLO_BURN.value(labels={"lane": lane}) == want
+        snap = slo.snapshot()["lanes"][lane]
+        window = outcomes[-WINDOW:]
+        assert snap["burn-rate"] == round(sum(window) / len(window)
+                                          / 0.01, 4)
+        assert snap["breaches"] == sum(outcomes)
+
     def test_queue_ring_bounded_and_sampled(self):
         slo = SLOTracker()
         for i in range(300):

@@ -266,6 +266,27 @@ class TestObserveManyEdgeCases:
                   if "_bucket" in l]
         assert counts == sorted(counts)
 
+    def test_bucket_counts_match_a_cumulative_recount(self):
+        """Each observation lands in one bucket and exposition
+        accumulates: every ``le`` line equals a direct count of the
+        observations at or below it, bucket edges included."""
+        import random
+        rng = random.Random(7)
+        buckets = (0.001, 0.01, 0.1, 1.0)
+        h = Histogram("cilium_tpu_test_many_recount", "rc",
+                      buckets=buckets)
+        values = [rng.choice(buckets + (-1.0, 0.0, 2.0, 0.05, 0.0005))
+                  for _ in range(500)]
+        for v in values:
+            h.observe(v)
+        lines = h.expose()
+        for ub in buckets:
+            want = sum(v <= ub for v in values)
+            assert f'cilium_tpu_test_many_recount_bucket{{le="{ub!r}"}} ' \
+                f"{want}" in lines
+        assert f'cilium_tpu_test_many_recount_bucket{{le="+Inf"}} ' \
+            f"{len(values)}" in lines
+
     def test_numpy_integer_counts_coerce(self):
         import numpy as np
         h = Histogram("cilium_tpu_test_many_np", "np",
@@ -400,25 +421,37 @@ class TestMapPressure:
 
 class TestJitTelemetry:
     def test_hit_miss_classification(self):
-        from cilium_tpu.observability.jitstats import JitTelemetry
+        """Compiles come from JAX's compile events, by jitted function;
+        a persistent-cache hit reported inside a compile makes it a
+        hit, any other event is not a compile."""
+        from cilium_tpu.observability.jitstats import (CACHE_HIT_EVENT,
+                                                       COMPILE_EVENT,
+                                                       JitTelemetry)
         t = JitTelemetry()
-        assert t.record("step", 1, 256, 1.5) is True    # compile
-        assert t.record("step", 1, 256, 0.001) is False  # hit
-        assert t.record("step", 1, 512, 1.2) is True    # new shape
-        assert t.record("step", 2, 256, 1.0) is True    # new program
+        t.on_duration(COMPILE_EVENT, 1.5, fun_name="step")   # compiled
+        t.on_event(CACHE_HIT_EVENT)                           # loaded
+        t.on_duration(COMPILE_EVENT, 0.001, fun_name="step")
+        t.on_duration(COMPILE_EVENT, 1.2, fun_name="step")   # new shape
+        t.on_duration(COMPILE_EVENT, 1.0, fun_name="other")  # new program
+        t.on_duration("/jax/core/other_duration", 9.0, fun_name="step")
         rep = t.report()
-        assert rep["compiles"]["step"] == 3
+        assert rep["compiles"] == {"step": 3, "other": 1}
         assert rep["cache-hits"] == 1 and rep["cache-misses"] == 3
-        assert rep["compile-seconds"]["step"] == pytest.approx(3.7)
+        assert rep["compile-seconds"]["step"] == pytest.approx(2.701)
 
     def test_disabled_records_nothing(self):
-        from cilium_tpu.observability.jitstats import JitTelemetry
+        from cilium_tpu.observability.jitstats import (COMPILE_EVENT,
+                                                       JitTelemetry)
         t = JitTelemetry()
         t.enabled = False
-        assert t.record("step", 1, 256, 1.5) is False
+        t.on_duration(COMPILE_EVENT, 1.5, fun_name="step")
         assert t.report()["cache-misses"] == 0
+        assert t.report()["compiles"] == {}
 
     def test_engine_accounts_compiles_and_hits(self):
+        """The engine's first dispatch compiles its step (counted under
+        the step's name); a second at the same geometry is a jit-cache
+        hit and compiles nothing."""
         from cilium_tpu.datapath.engine import Datapath, \
             make_full_batch
         from cilium_tpu.policy.mapstate import PolicyMapState
@@ -429,12 +462,15 @@ class TestJitTelemetry:
         pkt = make_full_batch(endpoint=[0], saddr=[1], daddr=[2],
                               sport=[1], dport=[80])
         dp.process(pkt, now=10)
+        first = jit_telemetry.report()
         dp.process(pkt, now=11)
         after = jit_telemetry.report()
-        assert after["cache-misses"] >= before["cache-misses"] + 1
-        assert after["cache-hits"] >= before["cache-hits"] + 1
-        assert after["compiles"].get("datapath.process", 0) >= \
-            before["compiles"].get("datapath.process", 0) + 1
+        step = "jit(full_datapath_step)"
+        assert first["cache-misses"] >= before["cache-misses"] + 1
+        assert first["compiles"].get(step, 0) >= \
+            before["compiles"].get(step, 0) + 1
+        assert after["compiles"].get(step) == first["compiles"][step]
+        assert pipeline_report()["jit"]["compile"]["count"] >= 1
         assert after["device-bytes"].get("engine-tables", 0) > 0
 
     def test_engine_telemetry_disabled_is_silent(self):
@@ -446,11 +482,17 @@ class TestJitTelemetry:
         dp.load_policy([PolicyMapState()], revision=1,
                        ipcache_prefixes={})
         before = jit_telemetry.report()
+        stages_before = pipeline_report().get("engine-v4", {})
         pkt = make_full_batch(endpoint=[0], saddr=[1], daddr=[2],
                               sport=[1], dport=[80])
-        dp.process(pkt, now=10)
+        jit_telemetry.enabled = False
+        try:
+            dp.process(pkt, now=10)
+        finally:
+            jit_telemetry.enabled = True
         after = jit_telemetry.report()
         assert after["cache-misses"] == before["cache-misses"]
+        assert pipeline_report().get("engine-v4", {}) == stages_before
         assert not dp._pending_verdicts
 
 
@@ -473,6 +515,83 @@ class TestPipelineStages:
         text = registry.expose_text()
         assert 'cilium_tpu_pipeline_stage_seconds_count' \
             '{family="test-family2",stage="dispatch"}' in text
+
+    def test_stage_span_lands_on_the_profiler_timeline(self, tmp_path):
+        """``stage()`` records the slice like ``record_stage`` and puts
+        it on a profile as ``<family>.<name>`` with its metadata."""
+        import glob
+        import os
+
+        import jax
+        from jax.profiler import ProfileData
+
+        from cilium_tpu.observability.stages import stage
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with stage("test-family3", "pack", launch=3):
+                time.sleep(0.002)
+        finally:
+            jax.profiler.stop_trace()
+        rep = pipeline_report()["test-family3"]["pack"]
+        assert rep["count"] == 1 and rep["total-s"] >= 0.002
+        path, = glob.glob(os.path.join(str(tmp_path), "plugins",
+                                       "profile", "*", "*.xplane.pb"))
+        found = [dict(e.stats) for p in ProfileData.from_file(path).planes
+                 for ln in p.lines for e in ln.events
+                 if e.name == "test-family3.pack"]
+        assert len(found) == 1 and found[0]["launch"] == 3
+
+    def test_gc_is_timed_while_the_stage_lock_is_held(self):
+        """A collection that starts while another thread holds the
+        stage lock finishes (the hook takes no lock) and is counted
+        by generation under family ``runtime``."""
+        import gc
+
+        from cilium_tpu.observability import stages
+        before = pipeline_report()["runtime"]["gc-gen2"]["count"]
+        held, release = threading.Event(), threading.Event()
+
+        def holder():
+            with stages._lock:
+                held.set()
+                release.wait(30)
+
+        h = threading.Thread(target=holder, daemon=True)
+        h.start()
+        assert held.wait(10)
+        collector = threading.Thread(target=gc.collect, daemon=True)
+        collector.start()
+        collector.join(timeout=30)
+        finished = not collector.is_alive()
+        release.set()
+        h.join(timeout=10)
+        assert finished, "gc.collect() blocked on the stage lock"
+        rep = pipeline_report()["runtime"]
+        assert rep["gc-gen2"]["count"] >= before + 1
+        assert rep["gc-gen2"]["total-s"] > 0
+        assert set(rep) == {"gc-gen0", "gc-gen1", "gc-gen2"}
+
+
+# ------------------------------------------------ named stages of the step
+
+class TestStepScopes:
+    def test_step_text_names_its_stages(self):
+        """Each stage of the fused step is a named scope: the lowered
+        program's locations and the compiled program's op names carry
+        them."""
+        from cilium_tpu.datapath.engine import Datapath
+        from cilium_tpu.datapath.pipeline import PACKED_FIELDS
+        from cilium_tpu.policy.mapstate import PolicyMapState
+        dp = Datapath(ct_slots=1 << 8)
+        dp.load_policy([PolicyMapState()], revision=1,
+                       ipcache_prefixes={"10.0.0.0/8": 2})
+        packed = np.zeros((len(PACKED_FIELDS), 16), np.int32)
+        lowered = dp._step_packed.lower(*dp._lower_args_packed(packed))
+        texts = (lowered.as_text(debug_info=True),
+                 lowered.compile().as_text())
+        for text in texts:
+            for scope in ("policy", "ipcache", "ct"):
+                assert f"/{scope}/" in text, scope
 
 
 # ----------------------------------------------- previously-dead metric wires

@@ -14,7 +14,10 @@ Pins the PR's contracts:
 - with the shared dispatcher serializing device work, the engine-lock
   convoy is gone: lock-wait no longer dominates dispatch under
   concurrent callers, and the serving stages expose exactly one
-  blocking boundary ("complete").
+  blocking boundary ("complete", with its device->host copy "d2h");
+- one launch records each of its stages once, on the profiler's
+  timeline too, under one launch number its tickets carry; the
+  supervisor's oracle refresh is a span with its four parts.
 """
 
 import asyncio
@@ -413,12 +416,108 @@ def test_lock_wait_no_longer_dominates_under_concurrent_callers():
     # so waiting on the engine lock is negligible next to dispatch
     assert eng["lock-wait"]["total-s"] < 0.5 * eng["dispatch"]["total-s"], eng
     srv = rep[disp.family]
-    assert set(srv) <= {"queue-wait", "pack", "dispatch", "complete"}
+    assert set(srv) <= {"queue-wait", "pack", "dispatch", "complete",
+                        "handoff", "d2h", "resolve"}
     blocking = sorted(s for s, d in srv.items()
                       if d["blocking-boundary"])
     # exactly ONE blocking boundary on the serving path, and it is the
-    # ticket-completion transfer (one batch behind the launch front)
-    assert blocking == ["complete"], srv
+    # ticket-completion transfer (one batch behind the launch front):
+    # "complete", and the device->host copy inside it
+    assert blocking == ["complete", "d2h"], srv
+
+
+def test_one_launch_records_each_stage_once(tmp_path):
+    """A supervised lane's single launch: pack, dispatch, complete,
+    handoff, d2h and resolve each once, the annotated ones on the
+    profile under the launch number its ticket carries."""
+    import glob
+    import os
+
+    import jax
+    from jax.profiler import ProfileData
+
+    from cilium_tpu.datapath.supervisor import DeviceSupervisor
+    from cilium_tpu.observability import stages
+    dp = _load_dp(telemetry=True)
+    disp = VerdictDispatcher(dp, supervisor=DeviceSupervisor(dp),
+                             lane=f"spans{time.monotonic_ns()}")
+    rng = np.random.default_rng(5)
+    try:
+        disp.submit_records(_chunk(rng, 8), 8).result(timeout=120)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            t = disp.submit_records(_chunk(rng, 8), 8)
+            t.result(timeout=120)
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        disp.close()
+    assert t.error is None and t.launch == disp.batches == 2
+    srv = stages.pipeline_report()[disp.family]
+    for name in ("queue-wait", "pack", "dispatch", "complete",
+                 "handoff", "d2h", "resolve"):
+        assert srv[name]["count"] == 2, (name, srv)
+    assert srv["handoff"]["total-s"] > 0
+    path, = glob.glob(os.path.join(str(tmp_path), "plugins", "profile",
+                                   "*", "*.xplane.pb"))
+    seen = {}
+    for p in ProfileData.from_file(path).planes:
+        for ln in p.lines:
+            for e in ln.events:
+                if e.name.startswith(disp.family + "."):
+                    seen.setdefault(e.name, []).append(
+                        dict(e.stats)["launch"])
+    assert seen == {f"{disp.family}.{n}": [2] for n in
+                    ("dispatch", "pack", "complete", "d2h", "resolve")}
+
+
+def test_lane_counts_outcomes_from_its_own_host_copy():
+    """policy_verdicts_total counts every record the lane answers, from
+    the verdicts it copied back; the engine queues no second read."""
+    from cilium_tpu.utils.metrics import POLICY_VERDICTS
+    dp = _load_dp(telemetry=True)
+    disp = VerdictDispatcher(dp, lane=f"outcomes{time.monotonic_ns()}")
+    outcomes = ("allowed", "denied", "redirected")
+    before = {o: POLICY_VERDICTS.value(labels={"outcome": o})
+              for o in outcomes}
+    rng = np.random.default_rng(9)
+    try:
+        v, _i = disp.submit_records(_chunk(rng, 40), 40).result(
+            timeout=120)
+    finally:
+        disp.close()
+    want = {"allowed": int((v == 0).sum()), "denied": int((v < 0).sum()),
+            "redirected": int((v > 0).sum())}
+    assert {o: POLICY_VERDICTS.value(labels={"outcome": o}) - before[o]
+            for o in outcomes} == want
+    assert not dp._pending_verdicts
+
+
+def test_oracle_refresh_is_a_span_with_its_parts():
+    from cilium_tpu.datapath.supervisor import DeviceSupervisor
+    from cilium_tpu.observability import stages
+    dp = _load_dp(telemetry=True)
+    parts = ("oracle-refresh", "copy-states", "compile-lpm",
+             "snapshot-ct", "decode-ct")
+
+    def read():
+        rep = stages.pipeline_report().get("supervisor", {})
+        zero = {"count": 0, "total-s": 0.0}
+        return {p: (rep.get(p, zero)["count"], rep.get(p, zero)["total-s"])
+                for p in parts}
+
+    before = read()
+    assert DeviceSupervisor(dp).oracle.refresh()
+    after = read()
+    assert {p: after[p][0] for p in parts} == \
+        {p: before[p][0] + 1 for p in parts}
+    took = {p: after[p][1] - before[p][1] for p in parts}
+    # the four parts run inside the span (totals are rounded to 1 us)
+    assert sum(took[p] for p in parts[1:]) <= took["oracle-refresh"] + 1e-5
+    # telemetry off: the refresh records nothing
+    dp.telemetry_enabled = False
+    DeviceSupervisor(dp).oracle.refresh()
+    assert read() == after
 
 
 # -------------------------------------------- VerdictBatcher split path
