@@ -49,6 +49,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ..compiler.lpm import CompiledLPM
 from ..observability.events import (EVENT_DATAPLANE_DEGRADED,
                                     EVENT_DATAPLANE_FAIL_STATIC,
                                     EVENT_DATAPLANE_REBUILD,
@@ -59,6 +60,7 @@ from ..observability.stages import NO_SPAN, stage
 from ..utils.faultinject import DeviceLaneFault
 from ..utils.metrics import (DATAPLANE_DEVICE_FAULTS,
                              DATAPLANE_FAIL_STATIC, DATAPLANE_MODE,
+                             DATAPLANE_ORACLE_LPM_BUILDS,
                              DATAPLANE_RECOVERIES,
                              DATAPLANE_SHARD_FAULTS,
                              DATAPLANE_SHARD_MODE)
@@ -126,7 +128,13 @@ class HostStaticOracle:
       the same states the device tables were compiled from — the
       ``oracle_verdict`` fallback chain over them IS last-known-good
       policy;
-    - a host ipcache LPM built from ``Datapath.ipcache_prefixes``.
+    - a host ipcache LPM read out of ``Datapath.compiled_ipcache``.
+
+    A refresh rebuilds only what changed: the states are held by
+    reference (their owner replaces a state, never mutates it), and the
+    host LPM is rebuilt only when the engine's compiled LPM is another
+    object than at the last refresh (the engine replaces it whole on
+    every ipcache load).
 
     ``new_flow_policy``: "oracle" (enforce last-known-good policy on
     host — the fail-static default), "deny" (no new flows while
@@ -142,6 +150,9 @@ class HostStaticOracle:
         self._ct: Dict[Tuple[int, int, int, int], Tuple[int, int]] = {}
         self._states: Dict[int, object] = {}
         self._lpm: List[Tuple[int, int, Dict[int, int]]] = []
+        self._lpm_of: Optional[CompiledLPM] = None  # what _lpm came from
+        self.lpm_builds = 0
+        self.lpm_reuses = 0
         self.refreshed_at = 0.0
         self.refreshes = 0
 
@@ -153,8 +164,10 @@ class HostStaticOracle:
         — a dead device must not wipe the last-known-good state.
 
         Timed as the ``supervisor`` stage ``oracle-refresh``, with its
-        parts ``copy-states``, ``compile-lpm``, ``snapshot-ct`` (holds
-        the engine lock, so it stalls dispatch) and ``decode-ct``."""
+        parts ``copy-states`` (takes the states by reference),
+        ``compile-lpm`` (reuses the last host LPM while the compiled
+        LPM is unchanged), ``snapshot-ct`` (holds the engine lock, so
+        it stalls dispatch) and ``decode-ct``."""
         dp = self.datapath
         telem = getattr(dp, "telemetry_enabled", False)
 
@@ -166,7 +179,12 @@ class HostStaticOracle:
                 states = {int(s): st for s, st in
                           (dp.host_policy_states() or {}).items()}
             with part("compile-lpm"):
-                lpm = self._compile_host_lpm(dict(dp.ipcache_prefixes))
+                compiled = dp.compiled_ipcache
+                with self._mu:
+                    lpm_of, lpm = self._lpm_of, self._lpm
+                built = compiled is not lpm_of
+                if built:
+                    lpm = self._host_lpm(compiled)
             try:
                 with part("snapshot-ct"):
                     snap, _snap6 = dp.snapshot_ct()
@@ -174,9 +192,15 @@ class HostStaticOracle:
                     ct = self._decode_ct(snap)
             except Exception:  # noqa: BLE001 — device read failed:
                 ct = None      # keep the last good CT view
+        if built:
+            DATAPLANE_ORACLE_LPM_BUILDS.inc()
         with self._mu:
             self._states = states
-            self._lpm = lpm
+            self._lpm, self._lpm_of = lpm, compiled
+            if built:
+                self.lpm_builds += 1
+            else:
+                self.lpm_reuses += 1
             if ct is not None:
                 self._ct = ct
             self.refreshed_at = time.monotonic()
@@ -185,32 +209,30 @@ class HostStaticOracle:
 
     @staticmethod
     def _decode_ct(snap) -> Dict:
-        k0 = np.ascontiguousarray(snap["k0"]).view(np.uint32)
-        k1 = np.ascontiguousarray(snap["k1"]).view(np.uint32)
-        k2 = np.ascontiguousarray(snap["k2"]).view(np.uint32)
-        k3 = np.ascontiguousarray(snap["k3"]).view(np.uint32)
-        exp = snap["expires"]
-        pp = snap["proxy_port"]
+        cols = np.stack([np.ascontiguousarray(snap[k]).view(np.uint32)
+                         for k in ("k0", "k1", "k2", "k3")])
         # exclude the sentinel slot (last row), like entry_count
-        live = np.flatnonzero(k3[:-1])
-        return {(int(k0[i]), int(k1[i]), int(k2[i]), int(k3[i])):
-                (int(exp[i]), int(pp[i])) for i in live.tolist()}
+        live = np.flatnonzero(cols[3, :-1])
+        keys = zip(*cols[:, live].tolist())
+        return dict(zip(keys, zip(snap["expires"][live].tolist(),
+                                  snap["proxy_port"][live].tolist())))
 
     @staticmethod
-    def _compile_host_lpm(prefixes: Dict[str, int]):
-        by_plen: Dict[int, Dict[int, int]] = {}
-        for cidr, ident in prefixes.items():
-            addr, _, plen_s = cidr.partition("/")
-            plen = int(plen_s) if plen_s else 32
-            a, b, c, d = (int(x) for x in addr.split("."))
-            val = (a << 24) | (b << 16) | (c << 8) | d
-            mask = 0 if plen == 0 else \
-                _pack_u32(0xFFFFFFFF << (32 - plen))
-            by_plen.setdefault(plen, {})[val & mask] = int(ident)
-        return [(plen, (0 if plen == 0 else
-                        _pack_u32(0xFFFFFFFF << (32 - plen))), table)
-                for plen, table in sorted(by_plen.items(),
-                                          reverse=True)]
+    def _host_lpm(lpm: Optional[CompiledLPM]
+                  ) -> List[Tuple[int, int, Dict[int, int]]]:
+        """[(prefix length, mask, {masked address: identity})], longest
+        first, read out of the compiled LPM's occupied slots."""
+        if lpm is None:
+            return []
+        out = []
+        for r, (plen, mask) in enumerate(zip(
+                lpm.prefix_lens.tolist(),
+                lpm.masks.view(np.uint32).tolist())):
+            occ = np.flatnonzero(lpm.key_b[r])
+            out.append((plen, mask, dict(zip(
+                lpm.key_a[r, occ].view(np.uint32).tolist(),
+                lpm.value[r, occ].tolist()))))
+        return out
 
     # ------------------------------------------------------ lookups
 
@@ -267,7 +289,9 @@ class HostStaticOracle:
                     "ipcache-prefixes": sum(len(t) for _p, _m, t
                                             in self._lpm),
                     "new-flow-policy": self.new_flow_policy,
-                    "refreshes": self.refreshes}
+                    "refreshes": self.refreshes,
+                    "lpm-builds": self.lpm_builds,
+                    "lpm-reuses": self.lpm_reuses}
 
 
 # --------------------------------------------------------------------------

@@ -262,12 +262,18 @@ class DeviceTableManager:
                     self._h_value.copy())
 
     def states_by_slot(self) -> Dict[int, PolicyMapState]:
-        """{table row slot: PolicyMapState copy} — the host-of-record
+        """{table row slot: stored PolicyMapState} — the host-of-record
         the fail-static oracle (datapath/supervisor.py) enforces while
         the device lane is degraded, and the source the recovery path
-        rebuilds device tensors from."""
+        rebuilds device tensors from.
+
+        The states are not copied.  Each is the manager's own copy,
+        made at ``attach``/``sync_endpoint`` and replaced there, never
+        mutated in place, so a caller that keeps this dict keeps
+        exactly the states as of the call (the oracle's last-known-good
+        view).  Callers must not mutate them."""
         with self._lock:
-            return {slot: PolicyMapState(self._state_of[ep_id])
+            return {slot: self._state_of[ep_id]
                     for ep_id, slot in self._slot_of.items()}
 
     def stats(self) -> Dict:

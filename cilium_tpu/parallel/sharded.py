@@ -375,6 +375,10 @@ class ShardedDatapath:
         return self.shards[0].ipcache_prefixes
 
     @property
+    def compiled_ipcache(self):
+        return self.shards[0].compiled_ipcache
+
+    @property
     def ipcache_prefixes6(self) -> Dict[str, int]:
         return self.shards[0].ipcache_prefixes6
 

@@ -311,6 +311,10 @@ DATAPLANE_FAIL_STATIC = registry.counter(
     "dataplane_fail_static_verdicts_total",
     "Verdicts served from the host fail-static oracle while the "
     "device lane is degraded")
+DATAPLANE_ORACLE_LPM_BUILDS = registry.counter(
+    "dataplane_oracle_lpm_builds_total",
+    "Host ipcache LPM builds by the fail-static oracle's refresh (a "
+    "refresh whose compiled ipcache is unchanged reuses the last one)")
 # Per-shard fault-domain series (parallel/sharded.py): when the verdict
 # dataplane is sharded across the device mesh, each ep-shard is its own
 # fault domain with its own breaker — these series carry the shard

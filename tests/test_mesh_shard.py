@@ -353,6 +353,14 @@ def test_sharded_manager_drives_plane_refresh():
     assert row["verdict"] == 0 and row["shard"] == g % N_SHARDS
     row = p.policy_replay([g], [999999], [5432], [6], [0])[0]
     assert row["verdict"] < 0
+    # the replicated ipcache: a host oracle over the plane reads shard
+    # 0's compiled LPM
+    from cilium_tpu.datapath.supervisor import HostStaticOracle
+    assert p.compiled_ipcache is p.shards[0].compiled_ipcache
+    oracle = HostStaticOracle(p)
+    oracle.refresh()
+    assert oracle._identity_of(0x0A010203) == 300
+    assert oracle._policy_verdict(g, 300, 5432, 6, INGRESS) == 0
 
 
 # ------------------------------------------------- per-shard pressure/GC
