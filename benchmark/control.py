@@ -8,6 +8,12 @@ installed through ``run.main``'s hook, under the serving lane, so its
 answers go through the same tickets, the same comparison and the same
 ``checks`` as the program's, and the run has to come out not correct.
 The program still runs each launch; only its verdicts are replaced.
+
+It answers with the run's own reference class: for a kind that brings
+a reference of its own, pass that class (``parts.Reference``, as
+``run.main`` is given ``parts``), so the control breaks that kind's
+guarantee:
+``hook=functools.partial(control.install, Reference=parts.Reference)``.
 """
 
 import numpy as np
@@ -15,8 +21,8 @@ import numpy as np
 import reference
 
 
-def install(system, dep):
-    ref = reference.Reference(dep)
+def install(system, dep, Reference=reference.Reference):  # noqa: N803
+    ref = Reference(dep)
 
     def wrap(step):
         def control(packed, now=None, payload=None):

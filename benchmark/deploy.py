@@ -9,6 +9,23 @@ answers from the same data.
 Each configuration names a ``kind``; its builder is
 ``deployments/<kind>.py``, found by that name, whose ``build(cfg,
 seed)`` returns a ``Deployment``.  A new kind is a new file.
+
+The deployment is one of a kind's roles (``byname.kind_parts``, which
+``run.py`` resolves once per run).  The others default to the shipped
+classes and may each come as a file of the kind's own:
+``systems/<kind>.py`` (``sut.System``), ``references/<kind>.py``
+(``reference.Reference``), ``flows/<kind>.py`` (``traffic.FlowSource``)
+and ``events/<kind>.py`` (``schedule(cfg, mix, dep, seed, seconds)``
+returning ``[(at_s, event), ...]``, played inside the window by
+``run.py``'s ``bench-events`` thread through ``system.apply(event)``;
+read only for a mix with an ``"events"`` key, and required there).  A
+kind's file may subclass the default or load another kind's file by
+name: a kind that reuses node-share's deployment has
+``deployments/<kind>.py`` return
+``byname.module("deployments", "node-share").build(cfg, seed)``.
+Like this module, the reference's side (``reference.py``,
+``traffic.py``, ``references/``, ``flows/``, ``events/``,
+``deployments/``) imports nothing of the program.
 """
 
 from __future__ import annotations

@@ -129,6 +129,25 @@ class Entry:
 
 
 class Reference:
+    """The default reference of a kind (``byname.py``).  A kind's
+    ``references/<kind>.py`` provides a ``Reference`` with what
+    ``run.py``, ``control.py`` and the tests call:
+
+    - ``Reference(dep, clock_offset)``: ``dep`` the deployment,
+      ``clock_offset`` the program's clock minus ``perf_counter``;
+    - ``check(rec, verdict, identity[, events])``, returning (verdict
+      mismatches, identity mismatches, records checked, ambiguous
+      records, examples).  ``events`` is passed only in a cell that
+      plays events: one stamp per event played, ``(event, due, start,
+      done, error)`` on the host clock;
+    - ``closing_checked``: records that met a closing entry;
+    - ``policy_only(rec)``: the control's (verdict, identity) arrays.
+
+    A subclass may widen ``policy_verdicts``, what policy may answer a
+    record that met no conntrack entry (a policy that changes inside
+    the window: either side of the change while it is in flight).
+    """
+
     def __init__(self, dep, clock_offset: float = 0.0):
         self.identity_of = host_lpm(dep.prefixes)
         self.policy = [Policy(row) for row in dep.policy]
@@ -137,6 +156,13 @@ class Reference:
 
     def _clock(self, t) -> int:
         return int(np.floor(t + self.offset))
+
+    def policy_verdicts(self, ep, ident, dport, proto, dirn, submit,
+                        resolve):
+        """The verdicts policy may give a record of endpoint ``ep`` that
+        met no conntrack entry, submitted and answered at ``submit`` and
+        ``resolve`` (host clock): here the endpoint's one policymap's."""
+        return (self.policy[ep].verdict(ident, dport, proto, dirn),)
 
     def policy_only(self, rec):
         """The control's answers: the identity and the policy verdict of
@@ -208,8 +234,8 @@ class Reference:
                         want.add(v)
                         via_entry.add(v)
                     else:
-                        want.add(self.policy[ep].verdict(want_i, dp, pr,
-                                                         dirn))
+                        want.update(self.policy_verdicts(
+                            ep, want_i, dp, pr, dirn, sub, res))
             if len(want) > 1:
                 ambiguous += 1
             if got_v not in want:

@@ -5,13 +5,28 @@
         --seconds <window> --trace <0|1>
 
 Everything a cell is made of is found by name in ``BENCHMARK.json``:
-its configuration file and that file's deployment kind
-(``deployments/<kind>.py``), its traffic mix (``traffic/<mix>.json``,
-data for the one generator in ``traffic.py``), and one reader per
-metric (``metrics/<metric>.py``): the cell's end-to-end metrics with
-``--trace 0``, its per-layer metrics with ``--trace 1``.  Records enter through the engine's serving
-lane (``Datapath.serving().submit_records``), configured as the daemon
-configures it, and come back through their tickets.
+its configuration file, its traffic mix (``traffic/<mix>.json``, data
+for the one generator in ``traffic.py``), and one reader per metric
+(``metrics/<metric>.py``): the cell's end-to-end metrics with
+``--trace 0``, its per-layer metrics with ``--trace 1``.  The
+configuration's ``kind`` brings the cell's roles (``byname.py``), each
+resolved once per run: its deployment (``deployments/<kind>.py``), and
+where the kind has a file of its own, its system (``systems/``, else
+``sut.System``), its reference (``references/``, else
+``reference.Reference``), its flows (``flows/``, else
+``traffic.FlowSource``) and its events (``events/``).  Records enter
+through the system's serving lane (``Datapath.serving().submit_records``
+by default), configured as the daemon configures it, and come back
+through their tickets.
+
+Events: a mix with an ``"events"`` key (the schedule's parameters) has
+its kind's ``events/<kind>.py`` build ``[(at_s, event), ...]`` at
+set-up; a kind without that file stops there.  One thread,
+``bench-events``, plays the events due inside the window, each at
+``t0 + at_s`` through ``system.apply(event)``, stamps it ``(due,
+start, done, error)`` on the host clock, and hands the stamps to the
+reference's check.  Warm-up and pre-roll play none.  Only such a cell
+gains the checks ``failed_events`` and ``events_played``.
 
 The last stdout line is the result (``PERF.md`` §2); the last stderr
 lines are each compared number beside its limit.  A run on anything but
@@ -33,6 +48,7 @@ import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
 import threading  # noqa: E402
+import traceback  # noqa: E402
 
 import numpy as np  # noqa: E402
 
@@ -42,8 +58,6 @@ sys.path.insert(0, HERE)
 sys.path.insert(1, ROOT)     # the program under test
 
 import byname  # noqa: E402
-import deploy  # noqa: E402
-import reference  # noqa: E402
 import traffic  # noqa: E402
 from traffic import FIELDS  # noqa: E402
 
@@ -187,10 +201,10 @@ def buckets_for(mix, frame_records):
             rows *= 2
         return rows
     if mix["loop"] == "closed":
-        top = min(MAX_BATCH, frame_records * mix["submitters"])
+        # whole frames, as many as every submitter's at once: the lane
+        # merges what is pending whatever MAX_BATCH says
         sizes = {bucket(k * frame_records)
-                 for k in range(1, mix["submitters"] + 1)
-                 if k * frame_records <= max(top, frame_records)}
+                 for k in range(1, mix["submitters"] + 1)}
     else:
         sizes = set()
         b = MIN_ROWS
@@ -221,12 +235,65 @@ def warm(lane, dep, buckets, claim_every=4):
                 raise RuntimeError(f"warm-up of bucket {b}: {tk.error!r}")
 
 
+# ------------------------------------------------------------- events
+
+class Events:
+    """The cell's events, played inside the window by one thread,
+    ``bench-events``: each at ``t0 + at_s`` through
+    ``system.apply(event)``.  ``played`` holds one stamp per event,
+    ``(event, due, start, done, error)`` on the host clock; an event
+    whose apply raised carries the exception's text and does not stop
+    the ones after it."""
+
+    def __init__(self, system, schedule):
+        self.system, self.schedule = system, schedule
+        self.played = []
+        self.thread = None
+
+    def start(self, t0):
+        import jax
+
+        def play():
+            for at_s, event in self.schedule:
+                due = t0 + at_s
+                now = time.perf_counter()
+                while now < due:
+                    time.sleep(min(due - now, 0.001))
+                    now = time.perf_counter()
+                error = None
+                with jax.profiler.TraceAnnotation("bench.event"):
+                    start = time.perf_counter()
+                    try:
+                        self.system.apply(event)
+                    except Exception as e:  # noqa: BLE001 — stamped, counted
+                        error = repr(e)
+                        traceback.print_exc()
+                    done = time.perf_counter()
+                self.played.append((event, due, start, done, error))
+
+        self.thread = threading.Thread(target=play, name="bench-events",
+                                       daemon=True)
+        self.thread.start()
+
+    def join(self, deadline):
+        self.thread.join(timeout=max(0.0, deadline - time.perf_counter()))
+        if self.thread.is_alive():
+            raise RuntimeError("an event was still being applied past the "
+                               "grace period")
+
+    def apply_s(self):
+        """(p50, max) of the seconds each event's apply took."""
+        took = [done - start for _e, _d, start, done, _x in self.played]
+        return [float(np.percentile(took, 50)), max(took)] if took else None
+
+
 # ------------------------------------------------------------- window
 
-def run_closed(system, pools, seconds, log, trace):
+def run_closed(system, pools, seconds, log, trace, events=None):
     """Closed loop: each submitter sends one frame (a round of its
     pool), waits for its answer, and sends the next; the next frame is
-    made while the answer is awaited."""
+    made while the answer is awaited.  ``events`` (``Events``) are
+    played from the window's start."""
     import jax
     lane = system.lane
     stop = threading.Event()
@@ -259,6 +326,8 @@ def run_closed(system, pools, seconds, log, trace):
                for s, p in enumerate(pools)]
     for t in threads:
         t.start()
+    if events is not None:
+        events.start(t0)
     snaps = trace.during(t0, seconds) if trace else None
     for t in threads:
         t.join(timeout=seconds + GRACE_S + 30)
@@ -266,13 +335,16 @@ def run_closed(system, pools, seconds, log, trace):
     alive = [t.name for t in threads if t.is_alive()]
     if alive:
         raise RuntimeError(f"submitters still waiting: {alive}")
+    if events is not None:
+        events.join(t_end + GRACE_S)
     return t0, t_end, {"generator_busy_share":
                        sum(busy) / (len(pools) * seconds)}, snaps
 
 
-def run_open(system, frames, seconds, log, trace):
+def run_open(system, frames, seconds, log, trace, events=None):
     """Open loop: every frame is sent when it is due, whatever is still
-    in flight; its latency runs from when it was due."""
+    in flight; its latency runs from when it was due.  ``events`` as in
+    ``run_closed``."""
     import jax
     lane = system.lane
     due, mat, meta, lo, hi, sampled = frames
@@ -309,6 +381,8 @@ def run_open(system, frames, seconds, log, trace):
     thread = threading.Thread(target=submitter, name="bench-submit",
                               daemon=True)
     thread.start()
+    if events is not None:
+        events.start(t0)
     snaps = trace.during(t0, seconds) if trace else None
     thread.join(timeout=seconds + GRACE_S + 30)
     deadline = time.perf_counter() + GRACE_S
@@ -318,6 +392,8 @@ def run_open(system, frames, seconds, log, trace):
         raise RuntimeError("the open-loop submitter fell behind its "
                            "schedule by more than the grace period")
     t_end = t0 + seconds
+    if events is not None:
+        events.join(t_end + GRACE_S)
     for j in range(nf):
         log.frame(t0 + due[j], t_sub[j], t_res[j], hi[j] - lo[j],
                   True if errs[j] else None)
@@ -428,12 +504,15 @@ def parse(argv):
     return ap.parse_args(argv)
 
 
-def main(argv=None, require_tpu=True, hook=None, overrides=None):
+def main(argv=None, require_tpu=True, hook=None, overrides=None,
+         parts=None):
     """One run; returns the result dict (also printed).  ``hook`` is
     called with the built system and the deployment before any traffic
     (the tests break the timed path there, and ``control.py`` puts the
     control in its place); ``overrides`` replaces configuration and mix
-    keys (the tests' small sizes, the builder's knee sweep)."""
+    keys (the tests' small sizes, a sweep of rates); ``parts`` are the
+    kind's roles (``byname.kind_parts``), found by the configuration's
+    ``kind`` when None (the tests bring kinds of their own)."""
     args = parse(argv)
     bench = load_bench()
     cell = next((w for w in bench["workloads"]
@@ -453,7 +532,12 @@ def main(argv=None, require_tpu=True, hook=None, overrides=None):
     mix.update(ov.get("traffic", {}))
     seed, seconds = args.seed, args.seconds
 
-    import sut
+    if parts is None:
+        parts = byname.kind_parts(cfg["kind"])
+    if "events" in mix and parts.events is None:
+        raise RuntimeError(f"the mix {cell['traffic']!r} has events and "
+                           f"the kind {parts.kind!r} has no events file "
+                           f"(events/{parts.kind}.py)")
     phases = {}
     t_ph = time.perf_counter()
 
@@ -466,9 +550,9 @@ def main(argv=None, require_tpu=True, hook=None, overrides=None):
     phase("start")
     # the deployment is the configuration's own, the same in every run
     # (as a model's weights would be); traffic comes from --seed
-    dep = deploy.build(cfg, cfg["deployment_seed"])
+    dep = parts.deployment.build(cfg, cfg["deployment_seed"])
     phase("deploy")
-    system = sut.System(cfg, dep)
+    system = parts.System(cfg, dep)
     phase("tables")
     if hook is not None:
         hook(system, dep)
@@ -478,7 +562,7 @@ def main(argv=None, require_tpu=True, hook=None, overrides=None):
     if closed:
         subs = mix["submitters"]
         share = mix["pool"] // subs
-        pools = [traffic.Pool(traffic.FlowSource(dep, mix, seed, s, subs),
+        pools = [traffic.Pool(parts.FlowSource(dep, mix, seed, s, subs),
                               share, sample_mod=sample_mod)
                  for s in range(subs)]
         # records per frame: one per local side of each flow
@@ -486,7 +570,7 @@ def main(argv=None, require_tpu=True, hook=None, overrides=None):
                             (pools[0].fl["s_ep"] >= 0).sum())
     else:
         prng = np.random.default_rng([seed, 5])
-        pool = traffic.Pool(traffic.FlowSource(dep, mix, seed, 0),
+        pool = traffic.Pool(parts.FlowSource(dep, mix, seed, 0),
                             mix["pool"], perm=prng.permutation(mix["pool"]),
                             sample_mod=sample_mod)
         frame_records = 1
@@ -508,6 +592,12 @@ def main(argv=None, require_tpu=True, hook=None, overrides=None):
                 log.sample(part, pm, value, ts, tr)
     phase("preroll")
     frames = None if closed else open_frames(mix, seed, seconds, pool)
+    events = None
+    if "events" in mix:
+        due = parts.events.schedule(cfg, mix, dep, seed, seconds)
+        events = Events(system, sorted(
+            ((at, ev) for at, ev in due if 0 <= at < seconds),
+            key=lambda p: p[0]))
     phase("schedule")
     trace = Trace(system) if args.trace else None
     if trace:
@@ -517,10 +607,10 @@ def main(argv=None, require_tpu=True, hook=None, overrides=None):
     setup_s = time.perf_counter() - T_PROCESS
     if closed:
         t0, t_end, gen, snaps = run_closed(system, pools, seconds, log,
-                                           trace)
+                                           trace, events)
     else:
         t0, t_end, gen, snaps = run_open(system, frames, seconds, log,
-                                         trace)
+                                         trace, events)
     if trace:
         trace.stop()
     s_after = system.stats()
@@ -542,10 +632,11 @@ def main(argv=None, require_tpu=True, hook=None, overrides=None):
     # -- the reference, once the window is closed and the state freed
     t_ref = time.perf_counter()
     recs = log.records()
-    ref = reference.Reference(dep, CLOCK_OFFSET)
+    ref = parts.Reference(dep, CLOCK_OFFSET)
+    played = {} if events is None else {"events": events.played}
     bad_v, bad_i, n_checked, ambiguous, examples = ref.check(
         recs, recs["verdict"].astype(np.int64),
-        recs["identity"].astype(np.int64))
+        recs["identity"].astype(np.int64), **played)
     ref_s = time.perf_counter() - t_ref
 
     win = (t0, t_end, seconds)
@@ -557,6 +648,10 @@ def main(argv=None, require_tpu=True, hook=None, overrides=None):
               "fail_static_batches": [fail_static, "<=", 0],
               "failed_frames": [failed, "<=", 0],
               "records_checked": [n_checked, ">=", 1]}
+    if events is not None:
+        checks["failed_events"] = [
+            sum(e[4] is not None for e in events.played), "<=", 0]
+        checks["events_played"] = [len(events.played), ">=", 1]
     correct = all(v <= lim if op == "<=" else v >= lim
                   for v, op, lim in checks.values())
     device_out = dict(dev, memory_peak_bytes=peak)
@@ -593,6 +688,9 @@ def main(argv=None, require_tpu=True, hook=None, overrides=None):
             "closing_checked": ref.closing_checked,
             "examples": examples[:3],
             "setup_phases_s": phases, **gen}
+    if events is not None:
+        info.update(events_played=len(events.played),
+                    event_apply_s=events.apply_s())
     print(json.dumps(info, default=str), flush=True)
     for name, (v, op, lim) in checks.items():
         print(f"check {name} {v} {op} {lim}", file=sys.stderr)
@@ -608,7 +706,6 @@ if __name__ == "__main__":
         print(f"benchmark: {e}", file=sys.stderr, flush=True)
         rc = 3
     except Exception:  # noqa: BLE001 — any failure: no result line
-        import traceback
         traceback.print_exc()
         rc = 1
     sys.stdout.flush()

@@ -1,10 +1,12 @@
-"""The system under test, configured as the daemon configures it.
+"""The default system under test, configured as the daemon configures
+it.
 
-This is the only module of the benchmark that imports the program.  It
-loads a deployment (``deploy.py``) through the daemon's own table path
-and returns the engine and its serving lane; what it reads back is the
-lane's verdicts, the dispatcher's counters, the stage spans and the
-supervisor's status.
+A kind builds its system here unless it brings ``systems/<kind>.py``
+(``byname.py``); this module and those files are the only ones of the
+benchmark that import the program.  It loads a deployment
+(``deploy.py``) through the daemon's own table path and returns the
+engine and its serving lane; what it reads back is the lane's verdicts,
+the dispatcher's counters, the stage spans and the supervisor's status.
 """
 
 from __future__ import annotations
@@ -30,7 +32,15 @@ def to_state(row):
 
 
 class System:
-    """The engine with its tables loaded and its serving lane."""
+    """The engine with its tables loaded and its serving lane.
+
+    A kind's ``systems/<kind>.py`` provides a ``System(cfg, dep)`` with
+    what ``run.py``, ``control.py`` and the tests use: ``lane`` (the
+    serving lane: ``submit_records(soa, n)`` returning a ticket),
+    ``stats()``, ``stages()``, ``supervision()``, ``ct_entries()``,
+    ``geometry()`` (with ``ct_slots``), ``wrap_step(wrap)`` and
+    ``close()``; and, for a kind whose mixes play events,
+    ``apply(event)``, called inside the window by the events thread."""
 
     def __init__(self, cfg, dep):
         from cilium_tpu.datapath.engine import Datapath

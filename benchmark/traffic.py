@@ -69,7 +69,17 @@ def hash3(seed: int, a, b):
 class FlowSource:
     """An endless, seeded stream of new flows for one submitter
     (``stream``).  Flow ``i`` of the stream is the same for a given
-    (deployment, mix, seed, stream), however it is consumed."""
+    (deployment, mix, seed, stream), however it is consumed.
+
+    The default flows of a kind (``byname.py``).  A kind's
+    ``flows/<kind>.py`` provides a ``FlowSource`` with what ``run.py``,
+    ``Pool`` and ``packets`` use: ``FlowSource(dep, mix, seed, stream,
+    streams)``; the attributes ``mix``, ``seed`` and ``stream``; and
+    ``flows(start, n)``, a dict of ``n`` rows of ``c_ep`` and ``s_ep``
+    (the client's and the server's local endpoint, -1 for a remote
+    peer), ``caddr``, ``saddr``, ``cport``, ``sport``, ``proto`` and
+    ``len`` (packets in the flow, at least 1).  Tuples must never
+    repeat within a run (module docstring)."""
 
     CHUNK = 1 << 14
 
